@@ -333,9 +333,9 @@ def tp_collective_bytes():
           codes+scales a TP shard all-gathers per decode step (reduced
           qwen3-4b, kv4_attn8_packed — the same arrays `Engine.report`
           prices as tp_wire_bytes_per_step_layer).
-      tokens_per_s : the engine serving with tp=8 *requested* — on the
-          single-device bench job this exercises the replicate-not-
-          crash fallback end to end; a loose CPU tripwire.
+      tokens_per_s : the engine serving with tp = the visible device
+          count (1 on the single-device bench job); a loose CPU
+          tripwire.
     """
     import time
 
@@ -360,7 +360,7 @@ def tp_collective_bytes():
     params = model.init(jax.random.PRNGKey(0))
     ecfg = EngineConfig(page_size=8, n_pages=48, max_batch=4,
                         max_pages_per_req=6, token_budget=16,
-                        prefill_chunk=8, tp=8)
+                        prefill_chunk=8, tp=len(jax.devices()))
     engine = Engine(model, params, ecfg)
     g = engine.caches["groups"]["p0"]
     pool_layer = sum(int(g[k].nbytes)

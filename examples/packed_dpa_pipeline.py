@@ -15,7 +15,10 @@ This example walks the whole software face of that story:
   4. account the operand bytes per policy and check the 2x/4x/8x ratios,
   5. prove packing is free: packed and unpacked results are bit-identical.
 
-Run:  PYTHONPATH=src python examples/packed_dpa_pipeline.py
+The packed kernels run in the Pallas interpreter (Mosaic refuses the
+nibble unpack), so this walkthrough is a CPU demo.
+
+Run:  PYTHONPATH=src JAX_PLATFORMS=cpu python examples/packed_dpa_pipeline.py
 """
 import numpy as np
 
@@ -47,7 +50,7 @@ print(f"quantize+pack: x {x.shape} f32 ({x.size * 4} B) -> "
 wq, sw = _quant_operand(w, "fp4_e2m1", 0)
 y_packed = dm.dpa_matmul_prequant(
     qx_packed, pack_fp4_axis(wq, 0), sx, sw, fmt_x="fp4_e2m1",
-    fmt_w="fp4_e2m1", pack_x=True, pack_w=True)
+    fmt_w="fp4_e2m1", pack_x=True, pack_w=True, interpret=True)
 print(f"packed DPA matmul:   rel err vs f32 = {rel_err(y_packed):.3f} "
       "(fp4 operands: quantization error, not packing error)")
 
@@ -70,7 +73,7 @@ print("  (expected ~2x / ~4x / ~8x — Table I's operand-bandwidth story)")
 # same quantizer kernel, unpacked layout (byte per code) on both sides
 xq, sx2 = O.quantize_rows(x, "fp4_e2m1")
 y_unpacked = dm.dpa_matmul_prequant(xq, wq, sx2, sw, fmt_x="fp4_e2m1",
-                                    fmt_w="fp4_e2m1")
+                                    fmt_w="fp4_e2m1", interpret=True)
 bit_identical = np.array_equal(np.asarray(y_packed), np.asarray(y_unpacked))
 print(f"\npacked == unpacked bit-for-bit: {bit_identical}")
 assert bit_identical

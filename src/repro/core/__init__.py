@@ -12,7 +12,7 @@ from .linear import (apply_grouped_linear, apply_linear, dpa_dot,
                      init_grouped_linear, init_linear)
 from .policy import DPA_TERMS, POLICIES, TransPrecisionPolicy, get_policy
 from .quantize import (cast_to, compute_scale, decode_fp4, dequantize,
-                       encode_fp4, fake_quant, has_native_dtype, jnp_dtype,
+                       encode_fp4, fake_quant, jnp_dtype,
                        quant_dequant, quantize, quantize_blockwise)
 
 __all__ = [
@@ -21,7 +21,7 @@ __all__ = [
     "TransPrecisionPolicy", "POLICIES", "DPA_TERMS", "get_policy",
     "quantize", "quantize_blockwise", "dequantize", "quant_dequant",
     "fake_quant", "cast_to", "compute_scale", "jnp_dtype",
-    "encode_fp4", "decode_fp4", "has_native_dtype",
+    "encode_fp4", "decode_fp4",
     "init_linear", "apply_linear", "dpa_dot",
     "init_grouped_linear", "apply_grouped_linear",
 ]

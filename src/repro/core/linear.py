@@ -36,8 +36,6 @@ def init_linear(key, d_in: int, d_out: int, *, bias: bool = False,
 
 
 # jnp dtypes whose arrays are accepted *as-is* as pre-quantized weights.
-# (float4 only exists on newer JAX builds; on 0.4.x fp4 weights are uint8
-# codes and ride the kernel path instead.)
 NATIVE_NARROW = ("float8_e4m3fn", "float8_e5m2", "float4_e2m1fn")
 _NATIVE_NARROW = NATIVE_NARROW
 

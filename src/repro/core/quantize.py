@@ -15,48 +15,24 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import ml_dtypes
 
 from .formats import get_format
 
 
-def _probe_dtype(dt):
-    """Return dt if this JAX build can actually compute with it, else None
-    (jax 0.4.x predates native float4 support; ml_dtypes has the dtype but
-    jnp refuses it as an array dtype)."""
-    if dt is None:
-        return None
-    try:
-        jnp.zeros((1,), dt)
-        return dt
-    except (TypeError, ValueError):
-        return None
-
-
-_FP4_NATIVE = _probe_dtype(getattr(jnp, "float4_e2m1fn", None)) \
-    or _probe_dtype(getattr(ml_dtypes, "float4_e2m1fn", None))
-
-# FloatFormat -> native jnp storage dtype (None: emulated via uint8 codes)
+# FloatFormat -> native jnp storage dtype
 _JNP_DTYPE = {
     "fp32": jnp.float32,
     "fp16": jnp.float16,
     "bf16": jnp.bfloat16,
     "fp8_e4m3": jnp.float8_e4m3fn,
     "fp8_e5m2": jnp.float8_e5m2,
-    "fp4_e2m1": _FP4_NATIVE,
+    "fp4_e2m1": jnp.float4_e2m1fn,
 }
 
 
-def has_native_dtype(fmt) -> bool:
-    return _JNP_DTYPE[get_format(fmt).name] is not None
-
-
 def jnp_dtype(fmt) -> jnp.dtype:
-    """Storage dtype for fmt.  Emulated sub-byte formats (fp4 on JAX builds
-    without float4) store one E2M1 code per uint8 byte — the same container
-    ml_dtypes uses — so shape/byte accounting stays identical."""
-    dt = _JNP_DTYPE[get_format(fmt).name]
-    return jnp.dtype(dt) if dt is not None else jnp.dtype(jnp.uint8)
+    """Storage dtype for fmt."""
+    return jnp.dtype(_JNP_DTYPE[get_format(fmt).name])
 
 
 # -----------------------------------------------------------------------------
@@ -101,9 +77,34 @@ def absmax_block_scale(xb, target: float, *, axis=1):
     f32-normal floors — `compute_scale` restated for a resident block with
     a static Python-float target (Pallas-safe, shared by the quantize and
     fused-matmul kernels and their references so their bit contract cannot
-    drift)."""
+    drift).  The division is written as a multiply by the reciprocal:
+    XLA rewrites a division by a constant that way under jit, so the
+    explicit form gives the same bits jitted, eager, or in a kernel."""
     amax = jnp.max(jnp.abs(xb), axis=axis, keepdims=True)
-    return jnp.maximum(jnp.maximum(amax, 1e-30) / target, 2.0 ** -126)
+    return jnp.maximum(jnp.maximum(amax, 1e-30) * (1.0 / target),
+                       2.0 ** -126)
+
+
+def round_to_format(x, fmt):
+    """Round f32 `x` (already inside fmt's finite range) to the nearest
+    value of fmt's grid, ties to even, in f32 arithmetic; -> f32.
+
+    The value an ``x.astype(fmt).astype(f32)`` round trip gives, bit for
+    bit — but XLA on a TPU may drop such a convert pair as excess
+    precision, and then nothing is rounded at all.  Scaling by a power
+    of two and rounding to an integer cannot be dropped, and lowers the
+    same in XLA and in Mosaic.  For formats whose grid spacing stays in
+    the f32 normal range (fp8 e4m3/e5m2, fp16); others cast."""
+    fmt = get_format(fmt)
+    if fmt.emin - fmt.man_bits < -126:          # bf16, fp32
+        return x.astype(jnp_dtype(fmt)).astype(jnp.float32)
+    exp = (jax.lax.bitcast_convert_type(x, jnp.int32) >> 23) & 0xFF
+    # spacing of fmt's grid around x (its subnormals share emin's) and
+    # its inverse, built as exact powers of two from exponent fields
+    e = jnp.maximum(exp - 127, fmt.emin) - fmt.man_bits
+    step = jax.lax.bitcast_convert_type((e + 127) << 23, jnp.float32)
+    inv = jax.lax.bitcast_convert_type((127 - e) << 23, jnp.float32)
+    return jnp.round(x * inv) * step
 
 
 def quant_rows_grid(x, fmt, *, axis=-1):
@@ -127,7 +128,7 @@ def quant_rows_grid(x, fmt, *, axis=-1):
     if fmt.name == "fp4_e2m1":
         grid = decode_fp4(encode_fp4(y))
     else:
-        grid = y.astype(jnp_dtype(fmt)).astype(jnp.float32)
+        grid = round_to_format(y, fmt)
     return grid, scale
 
 
@@ -143,19 +144,15 @@ def compute_scale(x, fmt, *, axis=None, keepdims=True, eps=1e-30):
 
 
 def cast_to(x, fmt):
-    """Saturating RNE cast into fmt's native dtype (no scaling).
-
-    When the format has no native dtype in this JAX build (fp4 on 0.4.x)
-    the cast is emulated: values are RNE-rounded onto the E2M1 grid and
-    returned as f32 — bit-identical values, wide container.  Use
-    `encode_fp4` directly when the uint8 code representation is wanted."""
+    """Saturating RNE cast into fmt's native dtype (no scaling).  Use
+    `encode_fp4` directly when the uint8 E2M1 code representation is
+    wanted."""
     fmt = get_format(fmt)
     xf = x.astype(jnp.float32)
     xf = jnp.clip(xf, -fmt.max_finite, fmt.max_finite)
-    dt = _JNP_DTYPE[fmt.name]
-    if dt is None:
-        return decode_fp4(encode_fp4(xf))
-    return xf.astype(dt)
+    if fmt.name == "fp4_e2m1":
+        return xf.astype(_JNP_DTYPE[fmt.name])
+    return round_to_format(xf, fmt).astype(_JNP_DTYPE[fmt.name])
 
 
 def quantize(x, fmt, *, axis=None):

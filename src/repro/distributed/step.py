@@ -108,7 +108,7 @@ def make_compressed_train_step(model, opt_cfg: adamw.AdamWConfig, mesh,
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.collectives import CompressedReducer
-    from repro.distributed.tp import shard_map_compat
+    from repro.distributed.tp import shard_map
 
     loss_fn = make_loss_fn(model)
     reducer = CompressedReducer(fmt_name)
@@ -131,8 +131,8 @@ def make_compressed_train_step(model, opt_cfg: adamw.AdamWConfig, mesh,
         return new_state, metrics
 
     state_specs = {"params": P(), "opt": P(), "err": P(axis)}
-    return shard_map_compat(body, mesh, in_specs=(state_specs, P(axis)),
-                            out_specs=(state_specs, P()), axis=axis)
+    return shard_map(body, mesh, in_specs=(state_specs, P(axis)),
+                     out_specs=(state_specs, P()))
 
 
 def init_err_state(params, n_devices: int):

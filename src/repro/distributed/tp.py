@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -67,17 +66,13 @@ def require_mesh():
     return mesh
 
 
-def shard_map_compat(body, mesh, in_specs, out_specs, axis: str = AXIS):
-    """jax.shard_map across the jax pins (same dual path as flash_decode:
-    new-API axis_names/check_vma vs 0.4.x experimental check_rep=False,
-    all-manual with replicated P() specs)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names={axis},
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+def shard_map(body, mesh, in_specs, out_specs):
+    """`jax.shard_map` with every mesh axis manual.  The serving mesh is
+    (1, tp) ("data", "model"), so the size-1 "data" axis being manual
+    changes nothing, and jax rejects replicated out_specs on a partly
+    manual mesh."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _gather_pool(shards: dict, axis: str):
@@ -108,7 +103,7 @@ def sharded_paged_attn(attn_fn, q, cache, positions, *, axis: str = AXIS):
     in_specs = (P(), P(None, axis, None, None), P(None, axis, None, None),
                 P(None, axis, None, None), P(None, axis, None, None),
                 P(), P())
-    fn = shard_map_compat(body, mesh, in_specs, P(), axis)
+    fn = shard_map(body, mesh, in_specs, P())
     return fn(q, cache["k_codes"], cache["k_scale"], cache["v_codes"],
               cache["v_scale"], cache["block_table"],
               jnp.asarray(positions, jnp.int32))

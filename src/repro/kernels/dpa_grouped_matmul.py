@@ -24,8 +24,8 @@ Two entry points, mirroring the dense pair:
 
 Grid is (expert, M//bm, N//bn, K//bk) with the K step innermost
 (`arbitrary`), experts and output tiles parallel.  Validated on CPU via
-interpret=True against the XLA fake-quant reference; compiled path
-targets TPU.
+interpret=True against the XLA fake-quant reference; the unpacked
+variants compile through Mosaic (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -36,13 +36,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.formats import get_format
-from repro.kernels._compat import CompilerParams as _CompilerParams
-from repro.kernels.dpa_matmul import _quantize_block, _widen
+from repro.core.quantize import quant_rows_grid
+from repro.kernels.dpa_matmul import _widen, check_packed_lowering
 
 
 def _gmm_params():
-    return _CompilerParams(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel",
                              "arbitrary"))
 
@@ -75,7 +74,7 @@ def _grouped_prequant_kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref, acc_ref, *,
 def dpa_grouped_matmul_prequant(xq, wq, sx, sw, *, fmt_x: str, fmt_w: str,
                                 bm: int = 128, bk: int = 128, bn: int = 128,
                                 pack_x: bool = False, pack_w: bool = False,
-                                interpret: bool = True):
+                                interpret: bool):
     """(E,M,K) x (E,K,N) -> (E,M,N) f32 with per-expert fp32 accumulation.
 
     xq: quantized activation stack (native fp8/fp16/bf16 dtype, or uint8
@@ -90,6 +89,8 @@ def dpa_grouped_matmul_prequant(xq, wq, sx, sw, *, fmt_x: str, fmt_w: str,
     """
     assert not (pack_x and fmt_x != "fp4_e2m1"), "pack_x needs fp4 codes"
     assert not (pack_w and fmt_w != "fp4_e2m1"), "pack_w needs fp4 codes"
+    check_packed_lowering("dpa_grouped_matmul_prequant", pack_x or pack_w,
+                          interpret)
     E, M = xq.shape[0], xq.shape[1]
     K = xq.shape[2] * (2 if pack_x else 1)
     K2 = wq.shape[1] * (2 if pack_w else 1)
@@ -130,8 +131,7 @@ def dpa_grouped_matmul_prequant(xq, wq, sx, sw, *, fmt_x: str, fmt_w: str,
 # -----------------------------------------------------------------------------
 
 def _grouped_fused_kernel(x_ref, w_ref, sw_ref, o_ref, acc_ref, *, n_k: int,
-                          fmt_x: str, fmt_w: str, pack_w: bool,
-                          target: float):
+                          fmt_x: str, fmt_w: str, pack_w: bool):
     @pl.when(pl.program_id(3) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -140,7 +140,7 @@ def _grouped_fused_kernel(x_ref, w_ref, sw_ref, o_ref, acc_ref, *, n_k: int,
     # varies per (expert token row, K block), so it folds into this block's
     # partial product; only the K-invariant expert column scales wait for
     # the epilogue — identical numerics to the dense fused kernel.
-    xq, sx = _quantize_block(x_ref[0].astype(jnp.float32), fmt_x, target)
+    xq, sx = quant_rows_grid(x_ref[0], fmt_x)
     w = _widen(w_ref[0], fmt_w, packed=pack_w, axis=0)
     acc_ref[...] += jnp.dot(xq, w, preferred_element_type=jnp.float32) * sx
 
@@ -153,7 +153,7 @@ def _grouped_fused_kernel(x_ref, w_ref, sw_ref, o_ref, acc_ref, *, n_k: int,
                                              "bn", "pack_w", "interpret"))
 def dpa_grouped_matmul_fused(x, wq, sw, *, fmt_x: str, fmt_w: str,
                              bm: int = 128, bk: int = 128, bn: int = 128,
-                             pack_w: bool = False, interpret: bool = True):
+                             pack_w: bool = False, interpret: bool):
     """Fused quantize->grouped matmul: raw x (E,M,K) f32/bf16,
     pre-quantized (and optionally packed) expert weights -> (E,M,N) f32.
 
@@ -164,6 +164,7 @@ def dpa_grouped_matmul_fused(x, wq, sw, *, fmt_x: str, fmt_w: str,
     packed fp4 nibbles, 4x/2x for fp8/fp16.
     """
     assert not (pack_w and fmt_w != "fp4_e2m1"), "pack_w needs fp4 codes"
+    check_packed_lowering("dpa_grouped_matmul_fused", pack_w, interpret)
     E, M, K = x.shape
     K2 = wq.shape[1] * (2 if pack_w else 1)
     N = wq.shape[2]
@@ -178,7 +179,7 @@ def dpa_grouped_matmul_fused(x, wq, sw, *, fmt_x: str, fmt_w: str,
 
     kernel = functools.partial(
         _grouped_fused_kernel, n_k=n_k, fmt_x=fmt_x, fmt_w=fmt_w,
-        pack_w=pack_w, target=get_format(fmt_x).quant_target)
+        pack_w=pack_w)
     return pl.pallas_call(
         kernel,
         grid=(E, M // bm, N // bn, n_k),

@@ -31,7 +31,9 @@ Two entry points:
                         quantized-activation round-trip through HBM.
 
 Block shapes default to MXU-aligned (128 multiples).  Validated on CPU
-via interpret=True against `ref.py`; compiled path targets TPU.
+via interpret=True against `ref.py`; on a TPU the unpacked variants
+compile through Mosaic (tests/test_tpu_compile.py), and the packed ones
+refuse to (`check_packed_lowering`).
 """
 from __future__ import annotations
 
@@ -42,16 +44,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.formats import get_format
 from repro.core.packing import unpack_fp4_axis
-from repro.core.quantize import (absmax_block_scale, decode_fp4, encode_fp4,
-                                 jnp_dtype)
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.core.quantize import decode_fp4, quant_rows_grid
 
 
 def _mm_params():
-    return _CompilerParams(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def check_packed_lowering(kernel: str, packed: bool, interpret: bool):
+    """Refuse a packed-fp4 operand on the compiled (Mosaic) path.
+
+    The nibble unpack/pack interleaves two codes per byte along a minor
+    dimension, a reshape Mosaic refuses ("unsupported shape cast"), so
+    the packed variants run only in the interpreter.  Raising here keeps
+    a TPU run from silently falling back to the interpreter or to an XLA
+    route."""
+    if packed and not interpret:
+        raise NotImplementedError(
+            f"{kernel}: packed-fp4 operands do not lower through Mosaic "
+            "(the nibble unpack is an unsupported shape cast); serve an "
+            "unpacked preset such as w8a8_kv8_attn8 on a TPU")
 
 
 def _widen(x, fmt_name: str, *, packed: bool = False, axis: int = 0):
@@ -95,7 +109,7 @@ def _dpa_matmul_kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref, acc_ref, *,
 def dpa_matmul_prequant(xq, wq, sx, sw, *, fmt_x: str, fmt_w: str,
                         bm: int = 128, bk: int = 128, bn: int = 128,
                         pack_x: bool = False, pack_w: bool = False,
-                        interpret: bool = True):
+                        interpret: bool):
     """(M,K) x (K,N) -> (M,N) f32 with fp32 accumulation.
 
     xq: quantized operand (native fp8/fp16/bf16 dtype, or uint8 E2M1 codes
@@ -110,6 +124,7 @@ def dpa_matmul_prequant(xq, wq, sx, sw, *, fmt_x: str, fmt_w: str,
     """
     assert not (pack_x and fmt_x != "fp4_e2m1"), "pack_x needs fp4 codes"
     assert not (pack_w and fmt_w != "fp4_e2m1"), "pack_w needs fp4 codes"
+    check_packed_lowering("dpa_matmul_prequant", pack_x or pack_w, interpret)
     M = xq.shape[0]
     K = xq.shape[1] * (2 if pack_x else 1)
     K2 = wq.shape[0] * (2 if pack_w else 1)
@@ -147,30 +162,18 @@ def dpa_matmul_prequant(xq, wq, sx, sw, *, fmt_x: str, fmt_w: str,
 # fused quantize -> matmul (activations quantized in the kernel prologue)
 # -----------------------------------------------------------------------------
 
-def _quantize_block(xb, fmt: str, target: float):
-    """(bm, bk) f32 -> (values-on-the-format-grid f32, (bm,1) f32 scale).
-
-    Per-(row, K-block) absmax scaling — the same recipe as
-    `core.quantize.quantize_blockwise` with block == bk, computed in VMEM."""
-    scale = absmax_block_scale(xb, target)
-    y = jnp.clip(xb / scale, -target, target)
-    if fmt == "fp4_e2m1":
-        q = decode_fp4(encode_fp4(y))
-    else:
-        q = y.astype(jnp_dtype(fmt)).astype(jnp.float32)
-    return q, scale
-
-
 def _dpa_fused_kernel(x_ref, w_ref, sw_ref, o_ref, acc_ref, *, n_k: int,
-                      fmt_x: str, fmt_w: str, pack_w: bool, target: float):
+                      fmt_x: str, fmt_w: str, pack_w: bool):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # prologue: absmax -> scale -> saturating RNE cast, all in VMEM.  The
-    # scale varies per K block, so it is folded into this block's partial
-    # product here; only the K-invariant weight scales wait for the epilogue.
-    xq, sx = _quantize_block(x_ref[...].astype(jnp.float32), fmt_x, target)
+    # prologue: absmax -> scale -> saturating RNE cast, all in VMEM — the
+    # `quant_rows_grid` recipe per (row, K-block), shared with the
+    # reference.  The scale varies per K block, so it is folded into this
+    # block's partial product here; only the K-invariant weight scales
+    # wait for the epilogue.
+    xq, sx = quant_rows_grid(x_ref[...], fmt_x)
     w = _widen(w_ref[...], fmt_w, packed=pack_w, axis=0)
     acc_ref[...] += jnp.dot(xq, w, preferred_element_type=jnp.float32) * sx
 
@@ -183,7 +186,7 @@ def _dpa_fused_kernel(x_ref, w_ref, sw_ref, o_ref, acc_ref, *, n_k: int,
                                              "bn", "pack_w", "interpret"))
 def dpa_matmul_fused(x, wq, sw, *, fmt_x: str, fmt_w: str, bm: int = 128,
                      bk: int = 128, bn: int = 128, pack_w: bool = False,
-                     interpret: bool = True):
+                     interpret: bool):
     """Fused quantize->matmul: raw x (M,K) f32/bf16, pre-quantized (and
     optionally packed) weights -> (M,N) f32.
 
@@ -195,6 +198,7 @@ def dpa_matmul_fused(x, wq, sw, *, fmt_x: str, fmt_w: str, bm: int = 128,
     block=bk)` — *finer*-grained than the per-row unfused path.
     """
     assert not (pack_w and fmt_w != "fp4_e2m1"), "pack_w needs fp4 codes"
+    check_packed_lowering("dpa_matmul_fused", pack_w, interpret)
     M, K = x.shape
     K2 = wq.shape[0] * (2 if pack_w else 1)
     N = wq.shape[1]
@@ -207,8 +211,7 @@ def dpa_matmul_fused(x, wq, sw, *, fmt_x: str, fmt_w: str, bm: int = 128,
     bk_w = bk // 2 if pack_w else bk
 
     kernel = functools.partial(
-        _dpa_fused_kernel, n_k=n_k, fmt_x=fmt_x, fmt_w=fmt_w, pack_w=pack_w,
-        target=get_format(fmt_x).quant_target)
+        _dpa_fused_kernel, n_k=n_k, fmt_x=fmt_x, fmt_w=fmt_w, pack_w=pack_w)
     return pl.pallas_call(
         kernel,
         grid=(M // bm, N // bn, n_k),

@@ -49,9 +49,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import unpack_fp4
 from repro.core.quantize import decode_fp4, quant_rows_grid
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels.dpa_matmul import check_packed_lowering
 
 _NEG_INF = -1e30
+# the dequantized K/V rows (grid x f32 scale) are not bf16-exact; the DPA
+# contract multiplies them at f32, which a TPU matmul gives only at
+# HIGHEST precision (the default is one bf16 pass)
+_F32_DOT = jax.lax.Precision.HIGHEST
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -99,7 +103,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                                              "bq", "bk", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
                     scale=None, bq: int = 128, bk: int = 128,
-                    interpret: bool = True):
+                    interpret: bool):
     """(B,H,Sq,D),(B,Hkv,Sk,D),(B,Hkv,Sk,D) -> (B,H,Sq,D)."""
     B, H, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape
@@ -128,7 +132,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr)
@@ -219,7 +223,7 @@ def dpa_flash_attention(q, k, v, k_scale=None, v_scale=None, *, fmt: str,
                         fmt_kv: str | None = None, kv_quant: bool = False,
                         kv_packed: bool = False, causal: bool = True,
                         window=None, scale=None, bq: int = 128,
-                        bk: int = 128, interpret: bool = True):
+                        bk: int = 128, interpret: bool):
     """(B,H,Sq,D) x (B,Hkv,Sk,Dk) x (B,Hkv,Sk,Dk) -> (B,H,Sq,D).
 
     Raw path (kv_quant=False): k/v are float tensors, quantized per-row
@@ -229,6 +233,8 @@ def dpa_flash_attention(q, k, v, k_scale=None, v_scale=None, *, fmt: str,
     scales k_scale/v_scale (B,Hkv,Sk,1).  Both paths see bit-identical
     K/V values; the cache path just moves 2-8x fewer bytes HBM->VMEM.
     """
+    check_packed_lowering("dpa_flash_attention", kv_quant and kv_packed,
+                          interpret)
     B, H, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape
     g = H // Hkv
@@ -268,7 +274,7 @@ def dpa_flash_attention(q, k, v, k_scale=None, v_scale=None, *, fmt: str,
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -283,48 +289,52 @@ def _paged_decode_kernel(tab_ref, pos_ref, q_ref, kc_ref, ks_ref, vc_ref,
                          vs_ref, o_ref, k_s, v_s, *, n_pages: int, ps: int,
                          kv_heads: int, fmt: str, fmt_kv: str,
                          kv_packed: bool, scale: float, s_view: int):
-    """Grid (B * KV, n_pages): page steps stream request b's timeline —
-    pool page ``table[b, j]`` arrives via the BlockSpec index map — and
-    widen codes * scales into VMEM scratch; the last step runs the whole
-    DPA attention row.
+    """Grid (B, n_pages): page step j streams request b's pool page
+    ``table[b, j]`` — all KV heads, the block the BlockSpec index map
+    names — and widens codes * scales per head into VMEM scratch
+    (KV, S_view, hd); the last step runs the DPA attention row of every
+    head.
 
-    The final computation deliberately mirrors `models.decode_attn.
-    dpa_attention`'s einsum structure (batch dims (head, s=1), per-batch
-    (1, hd) x (hd, S) matvecs) instead of a flat (g, hd) @ (hd, S) dot:
-    XLA tiles the two shapes differently, and the einsum form keeps the
-    route bit-identical to the jnp gather fallback — the contract
-    `tests/test_exec_plan.py` pins at tol 0.
+    Blocks span whole (KV, wc) trailing dims of the pool, the tiling
+    Mosaic accepts; a head is picked inside the kernel.  The final
+    computation deliberately mirrors `models.decode_attn.dpa_attention`'s
+    einsum structure — one leading batch dim (here the head group), per-
+    batch (1, hd) x (hd, S) matvecs — instead of a flat (g, hd) @ (hd, S)
+    dot: XLA tiles the two shapes differently, and the batched form keeps
+    the route bit-identical to the jnp gather fallback under the
+    interpreter (the contract `tests/test_exec_plan.py` pins at tol 0),
+    while Mosaic lowers a single leading batch dim.
     """
-    i = pl.program_id(0)
+    b = pl.program_id(0)
     j = pl.program_id(1)
-    b = i // kv_heads
-    k_s[pl.ds(j * ps, ps), :] = _widen_kv(kc_ref[0, :, 0, :], fmt_kv,
-                                          kv_packed) * ks_ref[0, :, 0, :]
-    v_s[pl.ds(j * ps, ps), :] = _widen_kv(vc_ref[0, :, 0, :], fmt_kv,
-                                          kv_packed) * vs_ref[0, :, 0, :]
+    rows = pl.ds(pl.multiple_of(j * ps, ps), ps)
+    for h in range(kv_heads):
+        k_s[h, rows, :] = _widen_kv(kc_ref[0, :, h, :], fmt_kv,
+                                    kv_packed) * ks_ref[0, :, h, :]
+        v_s[h, rows, :] = _widen_kv(vc_ref[0, :, h, :], fmt_kv,
+                                    kv_packed) * vs_ref[0, :, h, :]
 
     @pl.when(j == n_pages - 1)
     def _compute():
-        g, hd = q_ref.shape[1], q_ref.shape[2]
-        qg, qs = quant_rows_grid(q_ref[0][:, None, None, :], fmt)
-        k_all = jnp.broadcast_to(k_s[...][None, :, None, :],
-                                 (g, s_view, 1, hd))
-        v_all = jnp.broadcast_to(v_s[...][None, :, None, :],
-                                 (g, s_view, 1, hd))
-        logits = jnp.einsum("bshd,bthd->bhst", qg, k_all,
-                            preferred_element_type=jnp.float32)
-        logits = logits * qs.transpose(0, 2, 1, 3) * scale
-        kpos = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 3)
-        logits = jnp.where(kpos <= pos_ref[b], logits, _NEG_INF)
-        m = jnp.max(logits, axis=-1, keepdims=True)
-        p = jnp.exp(logits - m)                       # f32 softmax core
-        pg, psq = quant_rows_grid(p, fmt)
-        den = jnp.sum(pg, axis=-1, keepdims=True) * psq
-        num = jnp.einsum("bhst,bthd->bshd", pg, v_all,
-                         preferred_element_type=jnp.float32)
-        num = num * psq.transpose(0, 2, 1, 3)
-        out = num / jnp.maximum(den, 1e-30).transpose(0, 2, 1, 3)
-        o_ref[0] = out[:, 0, 0, :].astype(o_ref.dtype)
+        g, hd = q_ref.shape[2], q_ref.shape[3]
+        for h in range(kv_heads):
+            qg, qs = quant_rows_grid(q_ref[0, h][:, None, :], fmt)
+            k_all = jnp.broadcast_to(k_s[h][None], (g, s_view, hd))
+            v_all = jnp.broadcast_to(v_s[h][None], (g, s_view, hd))
+            logits = jnp.einsum("gsd,gtd->gst", qg, k_all,
+                                precision=_F32_DOT,
+                                preferred_element_type=jnp.float32)
+            logits = logits * qs * scale
+            kpos = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
+            logits = jnp.where(kpos <= pos_ref[b], logits, _NEG_INF)
+            m = jnp.max(logits, axis=-1, keepdims=True)
+            p = jnp.exp(logits - m)                   # f32 softmax core
+            pg, psq = quant_rows_grid(p, fmt)
+            den = jnp.sum(pg, axis=-1, keepdims=True) * psq
+            num = jnp.einsum("gst,gtd->gsd", pg, v_all, precision=_F32_DOT,
+                             preferred_element_type=jnp.float32) * psq
+            out = num / jnp.maximum(den, 1e-30)
+            o_ref[0, h] = out[:, 0, :].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("fmt", "fmt_kv", "kv_packed",
@@ -332,7 +342,7 @@ def _paged_decode_kernel(tab_ref, pos_ref, q_ref, kc_ref, ks_ref, vc_ref,
 def paged_decode_attention(q, k_codes, k_scale, v_codes, v_scale,
                            block_table, positions, *, fmt: str, fmt_kv: str,
                            kv_packed: bool = False, scale=None,
-                           interpret: bool = True):
+                           interpret: bool):
     """One decode step against the paged quantized KV cache.
 
     q: (B, 1, H, hd) (already rope'd at per-request positions);
@@ -342,44 +352,48 @@ def paged_decode_attention(q, k_codes, k_scale, v_codes, v_scale,
     request.  Same DPA contract as `dpa_flash_attention`'s cache mode —
     prologue dequant, f32 accumulation, f32 softmax glue — with the
     causal mask per request (row b attends key slots <= positions[b];
-    scratch/stale tail pages are masked off).
+    scratch/stale tail pages are masked off).  VMEM holds the widened
+    (KV, max_pages * page, hd) f32 K and V of one request.
     """
+    check_packed_lowering("paged_decode_attention", kv_packed, interpret)
     B, Sq, H, hd = q.shape
     assert Sq == 1, "paged decode serves single-token steps"
     _, n_pages = block_table.shape
-    _, ps, kv_heads, _ = k_codes.shape
+    _, ps, kv_heads, wc = k_codes.shape
     g = H // kv_heads
     s_view = n_pages * ps
     scale_v = float(scale if scale is not None else hd ** -0.5)
-    qr = q[:, 0].reshape(B * kv_heads, g, hd)
+    qr = q[:, 0].reshape(B, kv_heads, g, hd)
 
-    def page_idx(i, j, tab, pos, kv=kv_heads):
-        return (tab[i // kv, j], 0, i % kv, 0)
+    def page_idx(b, j, tab, pos):
+        return (tab[b, j], 0, 0, 0)
+
+    def req_idx(b, j, tab, pos):
+        return (b, 0, 0, 0)
 
     kernel = functools.partial(
         _paged_decode_kernel, n_pages=n_pages, ps=ps, kv_heads=kv_heads,
         fmt=fmt, fmt_kv=fmt_kv, kv_packed=kv_packed, scale=scale_v,
         s_view=s_view)
-    wc = k_codes.shape[-1]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B * kv_heads, n_pages),
+        grid=(B, n_pages),
         in_specs=[
-            pl.BlockSpec((1, g, hd), lambda i, j, tab, pos: (i, 0, 0)),
-            pl.BlockSpec((1, ps, 1, wc), page_idx),
-            pl.BlockSpec((1, ps, 1, 1), page_idx),
-            pl.BlockSpec((1, ps, 1, wc), page_idx),
-            pl.BlockSpec((1, ps, 1, 1), page_idx),
+            pl.BlockSpec((1, kv_heads, g, hd), req_idx),
+            pl.BlockSpec((1, ps, kv_heads, wc), page_idx),
+            pl.BlockSpec((1, ps, kv_heads, 1), page_idx),
+            pl.BlockSpec((1, ps, kv_heads, wc), page_idx),
+            pl.BlockSpec((1, ps, kv_heads, 1), page_idx),
         ],
-        out_specs=pl.BlockSpec((1, g, hd), lambda i, j, tab, pos: (i, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((s_view, hd), jnp.float32),
-                        pltpu.VMEM((s_view, hd), jnp.float32)],
+        out_specs=pl.BlockSpec((1, kv_heads, g, hd), req_idx),
+        scratch_shapes=[pltpu.VMEM((kv_heads, s_view, hd), jnp.float32),
+                        pltpu.VMEM((kv_heads, s_view, hd), jnp.float32)],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * kv_heads, g, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((B, kv_heads, g, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(block_table, jnp.int32), jnp.asarray(positions, jnp.int32),

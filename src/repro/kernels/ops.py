@@ -2,8 +2,9 @@
 
 These are what the execution-plan layer dispatches to: they quantize per
 the policy, pad to block multiples, dispatch the kernel, and undo
-padding.  On CPU they run in interpret mode (`REPRO_PALLAS_INTERPRET=0`
-to force compiled mode on real TPUs).
+padding.  On a TPU backend the kernels compile through Mosaic; on any
+other backend they run in the Pallas interpreter (`_interpret`, decided
+per call from the backend, never at import).
 
 Route selection does NOT live here: `repro.kernels.registry` registers
 each pipeline below as a `core.exec_plan` route with an explicit
@@ -13,20 +14,40 @@ identical to the call sites that use the plan directly.
 """
 from __future__ import annotations
 
-import os
-
+import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import exec_plan
 from repro.core.packing import pack_fp4_axis
 from repro.core.policy import TransPrecisionPolicy, get_policy
 from repro.core.quantize import compute_scale, cast_to
+from repro.distributed import tp as TP
+from repro.distributed.sharding import _in_manual_region
 from repro.kernels import dpa_grouped_matmul as _gm
 from repro.kernels import dpa_matmul as _dm
 from repro.kernels import flash_attention as _fa
 from repro.kernels import quantize as _q
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+
+def _interpret() -> bool:
+    """Interpret mode follows the backend: Mosaic compiles the kernels
+    for a TPU, and the interpreter runs them everywhere else."""
+    return jax.default_backend() != "tpu"
+
+
+def _run(kernel, *args, **kw):
+    """Dispatch a Pallas kernel in the mode `_interpret` picks.  Under
+    the engine's tensor-parallel mesh every operand these wrappers pass
+    is replicated, and XLA cannot partition a Mosaic kernel, so each
+    device runs its own copy inside a shard_map (inside one already —
+    the sharded attention routes — the kernel is called as is)."""
+    kw["interpret"] = _interpret()
+    mesh = TP.active_mesh()
+    if mesh is None or _in_manual_region(mesh):
+        return kernel(*args, **kw)
+    return TP.shard_map(lambda *a: kernel(*a, **kw), mesh,
+                        (P(),) * len(args), P())(*args)
 
 
 def _pad_to(x, mult, axis):
@@ -76,9 +97,9 @@ def dpa_matmul_fused_pipeline(x, w, policy: TransPrecisionPolicy, *,
     wq, swp, pn, pack_w = _prep_weights(w, policy, bk, bn)
     x2p, pm = _pad_to(x2, bm_, 0)
     x2p, _ = _pad_to(x2p, bk, 1)
-    out = _dm.dpa_matmul_fused(
-        x2p, wq, swp, fmt_x=policy.fmt_acts, fmt_w=policy.fmt_weights,
-        bm=bm_, bk=bk, bn=bn, pack_w=pack_w, interpret=INTERPRET)
+    out = _run(
+        _dm.dpa_matmul_fused, x2p, wq, swp, fmt_x=policy.fmt_acts,
+        fmt_w=policy.fmt_weights, bm=bm_, bk=bk, bn=bn, pack_w=pack_w)
     if pm:
         out = out[: x2.shape[0]]
     if pn:
@@ -104,10 +125,10 @@ def dpa_matmul_prequant_pipeline(x, w, policy: TransPrecisionPolicy, *,
     xq, _ = _pad_to(xq, bk, 1)
     if pack_x:
         xq = pack_fp4_axis(xq, 1)
-    out = _dm.dpa_matmul_prequant(
-        xq, wq, sxp, swp, fmt_x=policy.fmt_acts,
+    out = _run(
+        _dm.dpa_matmul_prequant, xq, wq, sxp, swp, fmt_x=policy.fmt_acts,
         fmt_w=policy.fmt_weights, bm=bm_, bk=bk, bn=bn,
-        pack_x=pack_x, pack_w=pack_w, interpret=INTERPRET)
+        pack_x=pack_x, pack_w=pack_w)
     if pm:
         out = out[: x2.shape[0]]
     if pn:
@@ -156,9 +177,9 @@ def dpa_grouped_fused_pipeline(x, w, policy: TransPrecisionPolicy, *,
     wq, swp, pn, pack_w = _prep_grouped_weights(w3, policy, bk, bn)
     x3p, pm = _pad_to(x3, bm_, 1)
     x3p, _ = _pad_to(x3p, bk, 2)
-    out = _gm.dpa_grouped_matmul_fused(
-        x3p, wq, swp, fmt_x=policy.fmt_acts, fmt_w=policy.fmt_weights,
-        bm=bm_, bk=bk, bn=bn, pack_w=pack_w, interpret=INTERPRET)
+    out = _run(
+        _gm.dpa_grouped_matmul_fused, x3p, wq, swp, fmt_x=policy.fmt_acts,
+        fmt_w=policy.fmt_weights, bm=bm_, bk=bk, bn=bn, pack_w=pack_w)
     if pm:
         out = out[:, :M]
     if pn:
@@ -184,10 +205,10 @@ def dpa_grouped_prequant_pipeline(x, w, policy: TransPrecisionPolicy, *,
     xq, _ = _pad_to(xq, bk, 2)
     if pack_x:
         xq = pack_fp4_axis(xq, 2)
-    out = _gm.dpa_grouped_matmul_prequant(
-        xq, wq, sxp, swp, fmt_x=policy.fmt_acts,
-        fmt_w=policy.fmt_weights, bm=bm_, bk=bk, bn=bn,
-        pack_x=pack_x, pack_w=pack_w, interpret=INTERPRET)
+    out = _run(
+        _gm.dpa_grouped_matmul_prequant, xq, wq, sxp, swp,
+        fmt_x=policy.fmt_acts, fmt_w=policy.fmt_weights, bm=bm_, bk=bk,
+        bn=bn, pack_x=pack_x, pack_w=pack_w)
     if pm:
         out = out[:, :M]
     if pn:
@@ -222,9 +243,9 @@ def quantize_rows_pallas(x, *, fmt: str, pack: bool, bm=128):
     x2, pm = _pad_to(x, bm, 0)
     if pack:
         assert fmt == "fp4_e2m1", "pack=True is the fp4 pipeline"
-        q, s = _q.quantize_pack_rows(x2, bm=bm, interpret=INTERPRET)
+        q, s = _run(_q.quantize_pack_rows, x2, bm=bm)
     else:
-        q, s = _q.quantize_rows(x2, fmt=fmt, bm=bm, interpret=INTERPRET)
+        q, s = _run(_q.quantize_rows, x2, fmt=fmt, bm=bm)
     if pm:
         q, s = q[: x.shape[0]], s[: x.shape[0]]
     return q, s
@@ -232,9 +253,8 @@ def quantize_rows_pallas(x, *, fmt: str, pack: bool, bm=128):
 
 def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
                     bq=128, bk=128):
-    return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                               scale=scale, bq=bq, bk=bk,
-                               interpret=INTERPRET)
+    return _run(_fa.flash_attention, q, k, v, causal=causal, window=window,
+                scale=scale, bq=bq, bk=bk)
 
 
 def dpa_flash_attention(q, k, v, *, fmt, fmt_kv=None, causal=True,
@@ -245,10 +265,8 @@ def dpa_flash_attention(q, k, v, *, fmt, fmt_kv=None, causal=True,
     stay f32.  See `flash_attention.dpa_flash_attention` for the
     quantized-KV-cache entry point (codes + scales in, fewer bytes moved).
     """
-    return _fa.dpa_flash_attention(q, k, v, fmt=fmt, fmt_kv=fmt_kv,
-                                   causal=causal, window=window,
-                                   scale=scale, bq=bq, bk=bk,
-                                   interpret=INTERPRET)
+    return _run(_fa.dpa_flash_attention, q, k, v, fmt=fmt, fmt_kv=fmt_kv,
+                causal=causal, window=window, scale=scale, bq=bq, bk=bk)
 
 
 def paged_decode_attention(q, cache, positions, *, fmt, fmt_kv,
@@ -256,8 +274,7 @@ def paged_decode_attention(q, cache, positions, *, fmt, fmt_kv,
     """Block-table paged decode (route ``paged_decode/pallas_block_table``):
     unpacks the paged-cache pytree and dispatches the Pallas kernel —
     pages stream HBM->VMEM through the block table, no gathered view."""
-    return _fa.paged_decode_attention(
-        q, cache["k_codes"], cache["k_scale"], cache["v_codes"],
-        cache["v_scale"], cache["block_table"], positions, fmt=fmt,
-        fmt_kv=fmt_kv, kv_packed=kv_packed, scale=scale,
-        interpret=INTERPRET)
+    return _run(
+        _fa.paged_decode_attention, q, cache["k_codes"], cache["k_scale"],
+        cache["v_codes"], cache["v_scale"], cache["block_table"], positions,
+        fmt=fmt, fmt_kv=fmt_kv, kv_packed=kv_packed, scale=scale)

@@ -22,6 +22,7 @@ from repro.core.formats import get_format
 from repro.core.packing import pack_fp4
 from repro.core.quantize import absmax_block_scale, jnp_dtype
 from repro.core.quantize import encode_fp4 as _encode_fp4
+from repro.kernels.dpa_matmul import check_packed_lowering
 
 
 def _quantize_kernel(x_ref, q_ref, s_ref, *, fmt: str, target: float):
@@ -47,8 +48,9 @@ def _quantize_pack_kernel(x_ref, q_ref, s_ref, *, target: float):
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
-def quantize_pack_rows(x, *, bm: int = 128, interpret: bool = True):
+def quantize_pack_rows(x, *, bm: int = 128, interpret: bool):
     """(M,K) f32/bf16 -> (packed fp4 codes (M, K//2) uint8, scale (M,1))."""
+    check_packed_lowering("quantize_pack_rows", True, interpret)
     M, K = x.shape
     assert M % bm == 0, f"M={M} must be a multiple of bm={bm}"
     assert K % 2 == 0, f"fp4 packing needs even K, got {K}"
@@ -67,7 +69,7 @@ def quantize_pack_rows(x, *, bm: int = 128, interpret: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("fmt", "bm", "interpret"))
-def quantize_rows(x, *, fmt: str, bm: int = 128, interpret: bool = True):
+def quantize_rows(x, *, fmt: str, bm: int = 128, interpret: bool):
     """(M,K) f32/bf16 -> (q:(M,K) fmt dtype | uint8 codes, scale:(M,1) f32)."""
     M, K = x.shape
     assert M % bm == 0, f"M={M} must be a multiple of bm={bm}"

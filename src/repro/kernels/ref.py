@@ -8,8 +8,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.core.formats import get_format
-from repro.core.quantize import (absmax_block_scale, cast_to, compute_scale,
-                                 decode_fp4, encode_fp4, jnp_dtype,
+from repro.core.quantize import (cast_to, compute_scale, decode_fp4,
                                  quant_rows_grid)
 
 
@@ -32,20 +31,12 @@ def dpa_matmul_fused_ref(x, wq, sw, *, fmt_x: str, fmt_w: str, bk: int):
     """Semantic spec of `dpa_matmul_fused`: per-(row, K-block) absmax
     quantization of raw x, blockwise scale folded into each partial
     product, weight column scales in the epilogue.  wq is *unpacked*."""
-    f = get_format(fmt_x)
-    target = f.quant_target
     M, K = x.shape
     xf = x.astype(jnp.float32)
     out = jnp.zeros((M, wq.shape[1]), jnp.float32)
     w = widen_ref(wq, fmt_w)
     for k0 in range(0, K, bk):
-        xb = xf[:, k0:k0 + bk]
-        scale = absmax_block_scale(xb, target)
-        y = jnp.clip(xb / scale, -target, target)
-        if fmt_x == "fp4_e2m1":
-            q = decode_fp4(encode_fp4(y))
-        else:
-            q = y.astype(jnp_dtype(fmt_x)).astype(jnp.float32)
+        q, scale = quant_rows_grid(xf[:, k0:k0 + bk], fmt_x)
         out = out + jnp.dot(q, w[k0:k0 + bk],
                             preferred_element_type=jnp.float32) * scale
     return out * sw.astype(jnp.float32)

@@ -63,8 +63,7 @@ def _kv_fmt(policy):
 def _mm_native(x, w, policy, **_):
     # pre-quantized weights (serving): keep them NATIVE in the dot —
     # fp8 x fp8 -> fp32 is the MXU DPA path itself, and it leaves no
-    # whole-stack weight convert for XLA to hoist out of the layer scan
-    # (measured 13.7 GiB on dbrx decode; EXPERIMENTS.md §Perf).
+    # whole-stack weight convert for XLA to hoist out of the layer scan.
     sx = compute_scale(x, policy.fmt_acts, axis=-1)
     xq = cast_to(x.astype(jnp.float32) / sx, policy.fmt_acts)
     out = jnp.dot(xq, w, preferred_element_type=jnp.float32)
@@ -482,7 +481,8 @@ exec_plan.register(
 # sharded paged attention: the pool lives 1/n per device ("model" axis,
 # within-page rows — the cache_spec rule), the wire carries format-width
 # codes + per-row scales, and the reassembled pool runs the exact
-# single-device op.  Bit-identical to single-device serving by
+# single-device op (the block-table kernel for decode, so tp=N runs what
+# tp=1 runs on every backend).  Bit-identical to single-device serving by
 # construction: no cross-device float reduction touches the softmax.
 # -----------------------------------------------------------------------------
 
@@ -490,9 +490,7 @@ def _pd_sharded(q, cache, positions, *, policy, scale):
     from functools import partial
 
     from repro.distributed import tp as TP
-    fn = partial(D.dpa_paged_decode_attn, fmt=policy.fmt_attn,
-                 fmt_kv=policy.fmt_kv, kv_packed=policy.kv_packed,
-                 scale=scale)
+    fn = partial(_pd_pallas, policy=policy, scale=scale)
     return TP.sharded_paged_attn(fn, q, cache, positions)
 
 
@@ -524,13 +522,13 @@ def _tp_wire_bytes(policy, ctx):
 
 
 exec_plan.register(
-    "paged_decode", "paged_decode_sharded", backend="xla", run=_pd_sharded,
-    priority=20, reference="jnp_gather", tol=0.0,
+    "paged_decode", "paged_decode_sharded", backend="pallas",
+    run=_pd_sharded, priority=20, reference="jnp_gather", tol=0.0,
     predicate=lambda policy, ctx: {
         "kv_quantized": policy.kv_quantized,
         "multi_device": ctx.get("n_devices", 1) > 1},
-    # gather-route compute bytes + the wire term the plan now prices
-    bytes_moved=lambda policy, ctx: 3 * _kv_rows_bytes(
+    # block-table kernel bytes + the wire term the plan now prices
+    bytes_moved=lambda policy, ctx: _kv_rows_bytes(
         policy, _pd_view_rows(ctx), ctx.get("hd", 0))
     + _tp_wire_bytes(policy, ctx),
     tests=("tests/test_tp_engine.py::test_tp_engine_bit_identical_"
@@ -538,8 +536,8 @@ exec_plan.register(
            "tests/test_tp_engine.py::test_tp_prefix_and_spec_decode_"
            "bit_identical"),
     note="shard_map over the \"model\" axis: all-gather pool shards at "
-         "format width (pure relayout), then the exact jnp_gather body — "
-         "bit-identical to single-device decode")
+         "format width (pure relayout), then the single-device block-"
+         "table kernel — tp=N decodes exactly as tp=1")
 
 exec_plan.register(
     "verify_attn", "verify_attn_sharded", backend="xla", run=_va_sharded,

@@ -146,9 +146,9 @@ class EngineConfig:
     # tensor-parallel width: shard the page pool across a (1, tp) "model"
     # mesh and serve through the `*_sharded` exec-plan routes (bit-
     # identical outputs; the wire carries format-width codes + scales).
-    # Falls back to 1 — replicate, never crash — when tp exceeds the
-    # visible devices or page_size % tp != 0 (the within-page row dim is
-    # the sharded one); report() states the reason.
+    # tp beyond the visible devices is an error; page_size % tp != 0
+    # (the within-page row dim is the sharded one) serves replicated,
+    # and report() states the reason.
     tp: int = 1
 
     @property
@@ -289,15 +289,16 @@ class Engine:
         pol = get_policy(cfg.policy)
         # tensor parallelism: a (1, tp) host mesh whose "model" axis
         # shards the page pool's within-page row dim (cache_spec's kv
-        # rule).  The fallback is replication, never a crash — the
-        # sharded routes' in_specs would reject a non-dividing dim.
+        # rule).  More devices than exist is an error; a page size the
+        # mesh does not divide serves replicated (the sharded routes'
+        # in_specs would reject a non-dividing dim) and says so.
         self.tp, self.tp_fallback, self._mesh = 1, "", None
         if ecfg.tp > 1:
             n_dev = len(jax.devices())
             if ecfg.tp > n_dev:
-                self.tp_fallback = (f"tp={ecfg.tp} exceeds {n_dev} visible "
-                                    "device(s); serving replicated")
-            elif ecfg.page_size % ecfg.tp:
+                raise ValueError(f"tp={ecfg.tp} exceeds the {n_dev} "
+                                 "visible device(s)")
+            if ecfg.page_size % ecfg.tp:
                 self.tp_fallback = (f"page_size={ecfg.page_size} not "
                                     f"divisible by tp={ecfg.tp}; serving "
                                     "replicated")
@@ -305,6 +306,10 @@ class Engine:
                 from repro.launch.mesh import make_host_mesh
                 self._mesh = make_host_mesh(n_data=1, n_model=ecfg.tp)
                 self.tp = ecfg.tp
+                # every device of the mesh holds the weights (a no-op
+                # when the caller already built them replicated)
+                params = jax.device_put(params, jax.sharding.NamedSharding(
+                    self._mesh, jax.sharding.PartitionSpec()))
         # the plan layer owns kernel selection: resolving the decode route
         # up front validates the policy (a raw-f32-cache policy has no
         # paged_decode route) and makes the report say which kernel runs
@@ -484,8 +489,10 @@ class Engine:
                             cache_spec(caches, self._mesh))
 
     def _tp_scope(self):
-        """Context the jit'd steps run (and so trace) under: the active
-        TP mesh the sharded exec-plan routes read back."""
+        """Context every jit'd step runs (and so traces) under: the
+        active TP mesh the sharded exec-plan routes read back, and under
+        which the kernel wrappers give each device its own copy of a
+        Mosaic kernel (`kernels.ops._run`)."""
         return (TP.activate(self._mesh) if self._mesh is not None
                 else contextlib.nullcontext())
 
@@ -507,6 +514,9 @@ class Engine:
                             (self._n_groups,) + self._table.shape))))
         tail = [dict(c, block_table=t) for c in self.caches["tail"]]
         self.caches = {"groups": {"p0": g}, "tail": tail}
+        if self._mesh is not None:
+            # tables replicated on the mesh, beside their pool shards
+            self.caches = self._shard_caches(self.caches)
 
     def _scatter_staging_to_pages(self, req: Request):
         """Copy the staged prompt rows into the request's pages (pure
@@ -748,9 +758,10 @@ class Engine:
             n = min(n, e.prefill_chunk - c0 % e.prefill_chunk)
         chunk = np.zeros((1, e.prefill_chunk), np.int32)
         chunk[0, :n] = req.prompt[c0:c0 + n]
-        logits, self._staging = self._prefill_fn(
-            self.params, {"tokens": jnp.asarray(chunk),
-                          "index": jnp.int32(c0)}, self._staging)
+        with self._tp_scope():
+            logits, self._staging = self._prefill_fn(
+                self.params, {"tokens": jnp.asarray(chunk),
+                              "index": jnp.int32(c0)}, self._staging)
         req.prefill_done += n
         if req.prefill_done == req.n_prompt:
             self._scatter_staging_to_pages(req)

@@ -37,8 +37,9 @@ import jax.numpy as jnp
 from repro.configs import get_config, reduce_config
 from repro.core.policy import get_policy
 from repro.distributed.step import make_serve_step
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
-from repro.models import build_model
+from repro.models import build_model, serving_init
 
 
 def report_plan(cfg, s_ctx: int) -> str:
@@ -100,17 +101,15 @@ def generate(model, params, prompt, n_gen: int, s_ctx: int):
     return jnp.concatenate(toks, axis=1)
 
 
-def run_engine(cfg, model, args):
-    """--engine mode: continuous batching over the paged quantized cache,
-    driven by an open-loop synthetic workload.  --spec-draft turns on
-    self-speculative decoding (draft under the named low-precision
-    policy, verify under --policy); --adaptive-draft replaces the static
-    draft policy with the acceptance-feedback precision ladder; --mixed
-    makes the traffic heterogeneous; --temperature/--top-k/--top-p
-    select sampling (default greedy)."""
+def make_engine(cfg, model, args, params=None):
+    """The --engine server: the tuned-DB knobs, the geometry and scheduler
+    flags as an `EngineConfig`, self-speculative (--spec-draft) or
+    adaptive (--adaptive-draft) drafting, sampling
+    (--temperature/--top-k/--top-p; default greedy), and serving params
+    built on the device in the compute dtype (`serving_init`) unless
+    `params` are given."""
     from repro.launch.engine import (Engine, EngineConfig, SamplerConfig,
-                                     SpecConfig, format_report,
-                                     synthetic_workload)
+                                     SpecConfig)
     from repro.runtime.controller import ControllerConfig, default_ladder
     if args.tuned_db:
         # export first so every exec_plan.resolve() below (engine
@@ -161,9 +160,17 @@ def run_engine(cfg, model, args):
             "--max-pages-per-req or --page-size")
     sampler = SamplerConfig(temperature=args.temperature, top_k=args.top_k,
                             top_p=args.top_p, seed=args.seed)
-    params = model.init(jax.random.PRNGKey(0))
-    engine = Engine(model, params, ecfg, sampler=sampler, spec=spec,
-                    adaptive=adaptive)
+    if params is None:
+        params = serving_init(model)(jax.random.PRNGKey(0))
+    return Engine(model, params, ecfg, sampler=sampler, spec=spec,
+                  adaptive=adaptive)
+
+
+def run_engine(cfg, model, args):
+    """--engine mode: the `make_engine` server driven by an open-loop
+    synthetic workload (--mixed makes the traffic heterogeneous)."""
+    from repro.launch.engine import format_report, synthetic_workload
+    engine = make_engine(cfg, model, args)
     reqs = synthetic_workload(
         args.requests, vocab=cfg.vocab_size, seed=args.seed,
         rate=args.rate, prompt_range=(max(1, args.prompt_len // 2),
@@ -183,7 +190,7 @@ def run_engine(cfg, model, args):
     return rep
 
 
-def main(argv=None):
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -254,26 +261,39 @@ def main(argv=None):
                          "with the acceptance-feedback controller "
                          "(repro.runtime.controller) instead of one "
                          "static --spec-draft policy")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def resolve_config(args):
+    """The served `ModelConfig`: --arch (cut by --reduced) under --policy.
+    The engine needs a quantized-KV preset; its default is the all-fp8
+    one, whose kernels compile for a TPU."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduce_config(cfg)
     if args.engine and not args.policy:
-        args.policy = "kv4_attn8_packed"    # engine needs a fmt_kv preset
+        args.policy = "w8a8_kv8_attn8"
     if args.policy:
         cfg = cfg.replace(policy=args.policy)
     if cfg.family in ("encdec", "vlm") or cfg.frontend == "stub":
         raise SystemExit("serve demo targets token-in/token-out archs")
+    return cfg
+
+
+def main(argv=None):
+    args = parser().parse_args(argv)
+    enable_compile_cache()
+    cfg = resolve_config(args)
     model = build_model(cfg)
-    mesh = make_host_mesh(n_model=args.n_model)
     if args.engine:
-        with mesh:
-            return run_engine(cfg, model, args)
+        # the engine places its own state: one device, or its (1, tp)
+        # mesh under --tp (an ambient host mesh would re-shard its steps)
+        return run_engine(cfg, model, args)
+    mesh = make_host_mesh(n_model=args.n_model)
     print(report_kv_cache(cfg, args.batch, args.prompt_len + args.gen))
     print(report_plan(cfg, args.prompt_len + args.gen))
     with mesh:
-        params = model.init(jax.random.PRNGKey(0))
+        params = serving_init(model)(jax.random.PRNGKey(0))
         prompt = jax.random.randint(jax.random.PRNGKey(1),
                                     (args.batch, args.prompt_len), 0,
                                     cfg.vocab_size)

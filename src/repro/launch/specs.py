@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import SHAPES, get_config
-from repro.models import build_model
+from repro.models import build_model, serving_init
 from repro.models.config import ModelConfig
 from repro.optim import adamw
 
@@ -32,10 +32,8 @@ def _cast_float(tree, dtype):
 
 def param_shapes(cfg: ModelConfig, *, serve: bool = False):
     model = build_model(cfg)
-    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     if serve:   # serving keeps weights in the compute dtype
-        shapes = _cast_float(shapes, jnp.bfloat16 if cfg.dtype == "bf16"
-                             else jnp.float32)
+        shapes = jax.eval_shape(serving_init(model), jax.random.PRNGKey(0))
         if cfg.serve_quant:   # weight-only storage format (matmul weights)
             from repro.core.quantize import jnp_dtype
             qdt = jnp_dtype(cfg.serve_quant)
@@ -47,7 +45,9 @@ def param_shapes(cfg: ModelConfig, *, serve: bool = False):
                     return jax.ShapeDtypeStruct(x.shape, qdt)
                 return x
             shapes = jax.tree_util.tree_map_with_path(q, shapes)
-    elif cfg.params_dtype == "bf16":
+        return shapes
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    if cfg.params_dtype == "bf16":
         shapes = _cast_float(shapes, jnp.bfloat16)
     return shapes
 

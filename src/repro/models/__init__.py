@@ -1,4 +1,4 @@
 from .config import ModelConfig
-from .registry import build_model
+from .registry import build_model, serving_init
 
-__all__ = ["ModelConfig", "build_model"]
+__all__ = ["ModelConfig", "build_model", "serving_init"]

@@ -48,7 +48,7 @@ class ModelConfig:
     attn_chunk: int = 0              # q-block-chunked attention (0 = off)
     use_flash: bool = False          # Pallas attention kernel (prefill)
     logits_chunk: int = 0            # beyond-paper: chunked loss (0 = off)
-    # --- perf-iteration knobs (EXPERIMENTS.md §Perf) ---
+    # --- perf-iteration knobs ---
     mesh_plan: str = "tp"            # "tp" (TP+SP on model) | "fully_dp"
     params_dtype: str = "fp32"       # train-state param storage dtype
     serve_param_mode: str = "fsdp"   # "fsdp" | "tp_only" (serve replication)

@@ -34,8 +34,7 @@ Shard-local KV-cache update + partial softmax.
 Auto-SPMD cannot see that a decode step's cache update touches one
 sequence shard, nor that attention against a sequence-sharded cache only
 needs (max, denom, weighted-V) per shard — it all-gathers the cache every
-layer (measured: 2 x S_shard x KV x hd gathers/layer, 80 GB/step on
-dbrx-132b decode; EXPERIMENTS.md §Perf).  This module is the manual
+layer (2 x S_shard x KV x hd per layer).  This module is the manual
 version: a shard_map over the "model" axis that
 
   1. writes k/v into the *owning* shard only (branchless in-range mask),
@@ -123,7 +122,14 @@ def dpa_attention(q, k, v, mask, *, fmt: str, fmt_kv=None, scale,
     if g > 1:
         k_eff = jnp.repeat(k_eff, g, axis=2)           # (B,Skv,H,hd)
         v_eff = jnp.repeat(v_eff, g, axis=2)
-    logits = jnp.einsum("bshd,bthd->bhst", qg, k_eff,
+    # head-major operands: both einsums batch over leading (B, H) dims,
+    # the per-batch matvec form the paged Pallas kernel reproduces bit
+    # for bit under the interpreter (see flash_attention._paged_decode_
+    # kernel).  HIGHEST: the dequantized K/V rows are not bf16-exact, and
+    # a TPU's default f32 matmul is one bf16 pass
+    logits = jnp.einsum("bhsd,bhtd->bhst", qg.transpose(0, 2, 1, 3),
+                        k_eff.transpose(0, 2, 1, 3),
+                        precision=jax.lax.Precision.HIGHEST,
                         preferred_element_type=jnp.float32)
     logits = logits * qs.transpose(0, 2, 1, 3) * scale
     logits = jnp.where(mask, logits, -1e30)
@@ -131,11 +137,11 @@ def dpa_attention(q, k, v, mask, *, fmt: str, fmt_kv=None, scale,
     p = jnp.exp(logits - m)                            # f32 softmax core
     pg, ps = quant_rows_grid(p, fmt)
     den = jnp.sum(pg, axis=-1, keepdims=True) * ps     # f32 denominator
-    num = jnp.einsum("bhst,bthd->bshd", pg, v_eff,
-                     preferred_element_type=jnp.float32)
-    num = num * ps.transpose(0, 2, 1, 3)
-    out = num / jnp.maximum(den, 1e-30).transpose(0, 2, 1, 3)
-    return out.astype(q.dtype)
+    num = jnp.einsum("bhst,bhtd->bhsd", pg, v_eff.transpose(0, 2, 1, 3),
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32) * ps
+    out = num / jnp.maximum(den, 1e-30)
+    return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
 def dpa_decode_attn(q, cache, offset, *, fmt: str, fmt_kv: str,
@@ -290,18 +296,8 @@ def flash_decode(q, k_new, v_new, k_cache, v_cache, offset, mesh,
                 P(None, axis, None, None), P())
     out_specs = (P(), P(None, axis, None, None),
                  P(None, axis, None, None))
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, axis_names={axis},
-                           check_vma=False)
-    else:   # jax 0.4.x: the experimental API, check_rep instead of vma.
-            # No auto= for the other mesh axes: partial-manual shard_map
-            # on 0.4.x lowers to a PartitionId op XLA's SPMD partitioner
-            # rejects ("PartitionId instruction is not supported").  All-
-            # manual with replicated P() specs is numerically equivalent
-            # here (test_flash_decode_sharded_matches_train pins it).
-        from jax.experimental.shard_map import shard_map as _shard_map
-        fn = _shard_map(body, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, axis_names={axis},
+                       check_vma=False)
     return fn(q, k_new, v_new, k_cache, v_cache,
               jnp.asarray(offset, jnp.int32))

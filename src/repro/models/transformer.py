@@ -151,15 +151,16 @@ def family_pattern(cfg: ModelConfig):
 
 
 def init_stack(key, cfg: ModelConfig, pattern, n_layers: int):
+    """Group-stacked block params: pattern slot i of group g draws from
+    layer key g * len(pattern) + i.  One vmapped init per slot builds the
+    stack directly (no per-layer copies beside it)."""
     P = len(pattern)
     n_groups, tail = divmod(n_layers, P)
     keys = jax.random.split(key, n_layers + 1)
-    groups = {}
-    for i, kind in enumerate(pattern):
-        stacked = [init_block(keys[g * P + i], cfg, kind)
-                   for g in range(n_groups)]
-        groups[f"p{i}"] = jax.tree.map(lambda *xs: jnp.stack(xs), *stacked) \
-            if n_groups > 1 else jax.tree.map(lambda x: x[None], stacked[0])
+    groups = {
+        f"p{i}": jax.vmap(lambda k, kind=kind: init_block(k, cfg, kind))(
+            keys[i:n_groups * P:P])
+        for i, kind in enumerate(pattern)}
     tail_params = [init_block(keys[n_groups * P + j], cfg, pattern[j])
                    for j in range(tail)]
     return {"groups": groups, "tail": tail_params}

@@ -76,17 +76,15 @@ def test_compressed_allreduce_error_feedback():
     true mean gradient."""
     r = _run("""
         from functools import partial
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collectives import ef_compress_allreduce
 
-        at = getattr(jax.sharding, "AxisType", None)
         mesh = jax.make_mesh((8,), ("data",),
-                             **({"axis_types": (at.Auto,)} if at else {}))
+                             axis_types=(jax.sharding.AxisType.Auto,))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 1024), jnp.float32)
         true_mean = jnp.mean(g, axis=0)
 
-        @partial(shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
+        @partial(jax.shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
                  out_specs=(P("data"), P("data")))
         def reduce_once(gs, es):
             m, e = ef_compress_allreduce(gs[0], es[0], "data")

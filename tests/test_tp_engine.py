@@ -7,8 +7,8 @@ engine — page pool sharded on the "model" axis, serving through the
 `paged_decode_sharded` / `verify_attn_sharded` exec-plan routes whose
 wire carries format-width codes + per-row scales — must emit exactly the
 tokens the tp=1 engine emits, across Table-I KV formats, through prefix-
-cache hits and speculative decoding, and must *replicate instead of
-crash* when the geometry doesn't divide the mesh axis.
+cache hits and speculative decoding; it replicates when the page size
+does not divide the mesh axis and refuses a tp wider than the devices.
 """
 import json
 import os
@@ -146,9 +146,9 @@ def test_tp_prefix_and_spec_decode_bit_identical():
 
 
 def test_tp_divisibility_fallback():
-    """Geometry that doesn't divide the mesh axis must replicate, not
-    crash: page_size % tp != 0 and tp > n_devices both fall back to
-    tp=1 with a stated reason and tp=1's exact outputs."""
+    """A page size the mesh axis does not divide serves replicated with
+    a stated reason and tp=1's exact outputs; a tp wider than the
+    visible devices is refused, never served on fewer devices."""
     r = _run(_ENGINE_PRELUDE + """
     cfg, model, params = build("kv4_attn8_packed")
     out = {}
@@ -156,8 +156,7 @@ def test_tp_divisibility_fallback():
                 token_budget=16, prefill_chunk=6)
     runs = {}
     for name, kw in (("ref", dict(page_size=12, tp=1)),
-                     ("indivisible", dict(page_size=12, tp=8)),
-                     ("too_wide", dict(page_size=12, tp=16))):
+                     ("indivisible", dict(page_size=12, tp=8))):
         eng = Engine(model, params, EngineConfig(**base, **kw))
         rep = eng.run(synthetic_workload(3, vocab=cfg.vocab_size, seed=0,
                                          prompt_range=(6, 18),
@@ -168,19 +167,18 @@ def test_tp_divisibility_fallback():
         "tp": runs["indivisible"][1]["tp"],
         "reason": runs["indivisible"][1].get("tp_fallback_reason", ""),
         "route": runs["indivisible"][1]["decode_route"]}
-    out["too_wide"] = {
-        "match": runs["ref"][0] == runs["too_wide"][0],
-        "tp": runs["too_wide"][1]["tp"],
-        "reason": runs["too_wide"][1].get("tp_fallback_reason", "")}
+    try:
+        Engine(model, params, EngineConfig(**base, page_size=12, tp=16))
+        out["too_wide"] = ""
+    except ValueError as e:
+        out["too_wide"] = str(e)
     print("RESULT:" + json.dumps(out))
     """)
     assert r["indivisible"]["match"], r["indivisible"]
     assert r["indivisible"]["tp"] == 1, r["indivisible"]
     assert "not divisible" in r["indivisible"]["reason"], r["indivisible"]
     assert r["indivisible"]["route"] != "paged_decode_sharded"
-    assert r["too_wide"]["match"], r["too_wide"]
-    assert r["too_wide"]["tp"] == 1, r["too_wide"]
-    assert "exceeds" in r["too_wide"]["reason"], r["too_wide"]
+    assert "exceeds" in r["too_wide"], r["too_wide"]
 
 
 def test_wire_collectives_parity():
@@ -208,8 +206,7 @@ def test_wire_collectives_parity():
         return full["k_codes"], full["k_scale"]
 
     spec = P(None, "model", None, None)
-    fn = TP.shard_map_compat(gather_body, mesh, (spec, spec),
-                             (P(), P()), "model")
+    fn = TP.shard_map(gather_body, mesh, (spec, spec), (P(), P()))
     gc, gs = fn(codes, scales)
     out["relayout_exact"] = bool(
         np.array_equal(np.asarray(gc), np.asarray(codes))
@@ -224,11 +221,11 @@ def test_wire_collectives_parity():
     def f32_body(xs):
         return jax.lax.psum(xs[0], "model")
 
-    want = np.asarray(TP.shard_map_compat(f32_body, mesh, (P("model"),),
-                                          P(), "model")(x))
+    want = np.asarray(TP.shard_map(f32_body, mesh, (P("model"),),
+                                   P())(x))
     for fmt in ("fp16", "fp8_e4m3"):
-        got = np.asarray(TP.shard_map_compat(
-            partial(red_body, fmt), mesh, (P("model"),), P(), "model")(x))
+        got = np.asarray(TP.shard_map(
+            partial(red_body, fmt), mesh, (P("model"),), P())(x))
         err = float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
         out["psum_" + fmt] = err
 
@@ -237,8 +234,8 @@ def test_wire_collectives_parity():
         return TP.all_gather_wire(xs, "model", fmt, gather_axis=0)
 
     for fmt in ("fp16", "fp8_e4m3"):
-        got = np.asarray(TP.shard_map_compat(
-            partial(ag_body, fmt), mesh, (P("model"),), P(), "model")(x))
+        got = np.asarray(TP.shard_map(
+            partial(ag_body, fmt), mesh, (P("model"),), P())(x))
         err = float(np.max(np.abs(got - x)) / np.max(np.abs(np.asarray(x))))
         out["gather_" + fmt] = err
     print("RESULT:" + json.dumps(out))

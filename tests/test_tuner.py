@@ -499,7 +499,7 @@ def test_bytes_model_paged_ops_match_nbytes():
         ("paged_decode", "jnp_gather", dict(ctx), 3 * view),
         ("verify_attn", "jnp_gather", dict(ctx, sq=4), (3 + 2 * 4) * view),
         ("paged_decode", "paged_decode_sharded", dict(ctx, n_devices=2),
-         3 * view + pool // 2),
+         1 * view + pool // 2),
         ("verify_attn", "verify_attn_sharded",
          dict(ctx, sq=4, n_devices=2), (3 + 2 * 4) * view + pool // 2),
     ]
