@@ -99,6 +99,17 @@ paging is pure relayout, so per-request greedy outputs are bit-identical
 to the static-batch `serve.generate` path (pinned by
 `tests/test_engine.py`), speculative or not (`tests/test_spec_decode.py`).
 
+Profiler spans: while a `jax.profiler` trace runs, every tick records
+`engine.step` (stats: step, decode_live, prefill_chunks, prefill_tokens,
+admitted, finished, waiting, host_reads, table_syncs) around its phases
+`engine.admit` (rid), `engine.decode` (live) with `engine.readback`,
+`engine.prefill_chunk` (rid, start, tokens), `engine.scatter` (rid,
+pages), `engine.first_token` (rid), `engine.table_sync`,
+`engine.spec_round` (k, live, rung), `engine.cow_copy` and
+`engine.prefix_load`.  They wrap host calls only; device work dispatched
+inside one runs asynchronously, so a span's length is host time.  With no
+trace running a span costs about a microsecond and sets no stats.
+
 Entry points: `Engine` (programmatic), `synthetic_workload` (open-loop
 Poisson traffic), `python -m repro.launch.serve --engine` (CLI demo).
 """
@@ -112,6 +123,7 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import exec_plan
 from repro.core import kvcache as KV
@@ -496,7 +508,7 @@ class Engine:
         return (TP.activate(self._mesh) if self._mesh is not None
                 else contextlib.nullcontext())
 
-    def _unshard_staging(self):
+    def _unshard_staging(self, tick):
         """Pull the staging cache back to one uncommitted device buffer.
         Gathering prefix rows out of the sharded pool leaves staging
         sharded; prefill must stay a single-device reduction (the tp=1
@@ -504,19 +516,22 @@ class Engine:
         pool scatter free to colocate with the committed pool."""
         self._staging = jax.tree.map(
             lambda x: jnp.asarray(np.asarray(x)), self._staging)
+        tick["host_reads"] += len(jax.tree.leaves(self._staging))
 
-    def _sync_tables(self):
+    def _sync_tables(self, tick):
         """Push the host block table into every layer's cache leaf."""
-        t = jnp.asarray(self._table)
-        g = self.caches["groups"]["p0"]
-        g = dict(g, block_table=jnp.asarray(np.ascontiguousarray(
-            np.broadcast_to(self._table[None],
-                            (self._n_groups,) + self._table.shape))))
-        tail = [dict(c, block_table=t) for c in self.caches["tail"]]
-        self.caches = {"groups": {"p0": g}, "tail": tail}
-        if self._mesh is not None:
-            # tables replicated on the mesh, beside their pool shards
-            self.caches = self._shard_caches(self.caches)
+        tick["table_syncs"] += 1
+        with TraceAnnotation("engine.table_sync"):
+            t = jnp.asarray(self._table)
+            g = self.caches["groups"]["p0"]
+            g = dict(g, block_table=jnp.asarray(np.ascontiguousarray(
+                np.broadcast_to(self._table[None],
+                                (self._n_groups,) + self._table.shape))))
+            tail = [dict(c, block_table=t) for c in self.caches["tail"]]
+            self.caches = {"groups": {"p0": g}, "tail": tail}
+            if self._mesh is not None:
+                # tables replicated on the mesh, beside their pool shards
+                self.caches = self._shard_caches(self.caches)
 
     def _scatter_staging_to_pages(self, req: Request):
         """Copy the staged prompt rows into the request's pages (pure
@@ -531,20 +546,21 @@ class Engine:
             rows = {k: staged[k][0] for k in KV.QUANT_KEYS}
             return KV.write_prefill_rows(pages, rows, ids, n, start=start)
 
-        g = self.caches["groups"]["p0"]
-        sg = self._staging["groups"]["p0"]
-        g = jax.vmap(copy_group)({k: g[k] for k in KV.QUANT_KEYS},
-                                 {k: sg[k] for k in KV.QUANT_KEYS})
-        self.caches["groups"]["p0"] = dict(self.caches["groups"]["p0"], **g)
-        for i, (pc, sc) in enumerate(zip(self.caches["tail"],
-                                         self._staging["tail"])):
-            rows = {k: sc[k][0] for k in KV.QUANT_KEYS}
-            self.caches["tail"][i] = KV.write_prefill_rows(pc, rows, ids, n,
-                                                           start=start)
-        if self._mesh is not None:
-            # eager scatter output sharding is compiler-chosen; pin the
-            # pool back to its canonical mesh layout (pure relayout)
-            self.caches = self._shard_caches(self.caches)
+        with TraceAnnotation("engine.scatter", rid=req.rid, pages=len(ids)):
+            g = self.caches["groups"]["p0"]
+            sg = self._staging["groups"]["p0"]
+            g = jax.vmap(copy_group)({k: g[k] for k in KV.QUANT_KEYS},
+                                     {k: sg[k] for k in KV.QUANT_KEYS})
+            self.caches["groups"]["p0"] = dict(self.caches["groups"]["p0"], **g)
+            for i, (pc, sc) in enumerate(zip(self.caches["tail"],
+                                             self._staging["tail"])):
+                rows = {k: sc[k][0] for k in KV.QUANT_KEYS}
+                self.caches["tail"][i] = KV.write_prefill_rows(pc, rows, ids, n,
+                                                               start=start)
+            if self._mesh is not None:
+                # eager scatter output sharding is compiler-chosen; pin the
+                # pool back to its canonical mesh layout (pure relayout)
+                self.caches = self._shard_caches(self.caches)
 
     def _cow_copy(self, src: int, dst: int, n_rows: int):
         """Copy the first `n_rows` rows of pool page `src` into the
@@ -556,16 +572,17 @@ class Engine:
             return {k: pool[k].at[dst, :n_rows].set(pool[k][src, :n_rows])
                     for k in KV.QUANT_KEYS}
 
-        g = self.caches["groups"]["p0"]
-        g2 = jax.vmap(copy_group)({k: g[k] for k in KV.QUANT_KEYS})
-        self.caches["groups"]["p0"] = dict(g, **g2)
-        for i, pc in enumerate(self.caches["tail"]):
-            self.caches["tail"][i] = dict(pc, **copy_group(pc))
-        if self._mesh is not None:
-            self.caches = self._shard_caches(self.caches)
+        with TraceAnnotation("engine.cow_copy"):
+            g = self.caches["groups"]["p0"]
+            g2 = jax.vmap(copy_group)({k: g[k] for k in KV.QUANT_KEYS})
+            self.caches["groups"]["p0"] = dict(g, **g2)
+            for i, pc in enumerate(self.caches["tail"]):
+                self.caches["tail"][i] = dict(pc, **copy_group(pc))
+            if self._mesh is not None:
+                self.caches = self._shard_caches(self.caches)
         self.cow_copies += 1
 
-    def _load_prefix_to_staging(self, req: Request):
+    def _load_prefix_to_staging(self, req: Request, tick):
         """Materialize the matched rows [0, prefill_skip) from the
         request's pages into the contiguous staging cache — the inverse
         relayout of `_scatter_staging_to_pages` — so the warm prefill's
@@ -581,16 +598,17 @@ class Engine:
                 out[k] = staged[k].at[0, :m].set(rows)
             return out
 
-        g = self.caches["groups"]["p0"]
-        sg = self._staging["groups"]["p0"]
-        new = jax.vmap(gather_group)({k: g[k] for k in KV.QUANT_KEYS},
-                                     {k: sg[k] for k in KV.QUANT_KEYS})
-        self._staging["groups"]["p0"] = dict(sg, **new)
-        for i, (pc, sc) in enumerate(zip(self.caches["tail"],
-                                         self._staging["tail"])):
-            self._staging["tail"][i] = dict(sc, **gather_group(pc, sc))
-        if self._mesh is not None:
-            self._unshard_staging()
+        with TraceAnnotation("engine.prefix_load"):
+            g = self.caches["groups"]["p0"]
+            sg = self._staging["groups"]["p0"]
+            new = jax.vmap(gather_group)({k: g[k] for k in KV.QUANT_KEYS},
+                                         {k: sg[k] for k in KV.QUANT_KEYS})
+            self._staging["groups"]["p0"] = dict(sg, **new)
+            for i, (pc, sc) in enumerate(zip(self.caches["tail"],
+                                             self._staging["tail"])):
+                self._staging["tail"][i] = dict(sc, **gather_group(pc, sc))
+            if self._mesh is not None:
+                self._unshard_staging(tick)
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -660,35 +678,36 @@ class Engine:
                 if match is not None:
                     self._unpin_match(match)
                 break                      # FIFO: don't starve the head
-            self.waiting.pop(0)
-            if self.spec is not None:
-                # lazy commit: reserve the lifetime worst case, pop only
-                # the prompt's pages now; rounds commit/roll back the rest
-                n0 = -(-req.n_prompt // self.ecfg.page_size)
-                self.alloc.reserve(fresh)
-                req.pages = shared + self.alloc.alloc(n0 - len(shared),
-                                                      reserved=True)
-                req.reserved_left = fresh - (n0 - len(shared))
-            else:
-                req.pages = shared + self.alloc.alloc(fresh)
-            if match is not None:
-                # stats count admissions, not retries: a request that
-                # waited several ticks for pages is still one query
-                self.prefix_queries += 1
-                req.prefill_skip = req.prefill_done = match.tokens
-                self.prefix_hits += match.tokens > 0
-                self.prefill_tokens_saved += match.tokens
-                if match.cow is not None:
-                    src, rows = match.cow
-                    # copy now, while the source pin is held; afterwards
-                    # the source's content no longer matters to us
-                    self._cow_copy(src, req.pages[len(shared)], rows)
-                    self.alloc.free([src])
-            if self.adaptive is not None:
-                req.rung = self.adaptive.start_rung
-                req.ctrl = CTRL.init_state(self.adaptive)
-            req.slot, req.state, req.t_admit = slot, PREFILL, now
-            self.slots[slot] = req
+            with TraceAnnotation("engine.admit", rid=req.rid):
+                self.waiting.pop(0)
+                if self.spec is not None:
+                    # lazy commit: reserve the lifetime worst case, pop only
+                    # the prompt's pages now; rounds commit/roll back the rest
+                    n0 = -(-req.n_prompt // self.ecfg.page_size)
+                    self.alloc.reserve(fresh)
+                    req.pages = shared + self.alloc.alloc(n0 - len(shared),
+                                                          reserved=True)
+                    req.reserved_left = fresh - (n0 - len(shared))
+                else:
+                    req.pages = shared + self.alloc.alloc(fresh)
+                if match is not None:
+                    # stats count admissions, not retries: a request that
+                    # waited several ticks for pages is still one query
+                    self.prefix_queries += 1
+                    req.prefill_skip = req.prefill_done = match.tokens
+                    self.prefix_hits += match.tokens > 0
+                    self.prefill_tokens_saved += match.tokens
+                    if match.cow is not None:
+                        src, rows = match.cow
+                        # copy now, while the source pin is held; afterwards
+                        # the source's content no longer matters to us
+                        self._cow_copy(src, req.pages[len(shared)], rows)
+                        self.alloc.free([src])
+                if self.adaptive is not None:
+                    req.rung = self.adaptive.start_rung
+                    req.ctrl = CTRL.init_state(self.adaptive)
+                req.slot, req.state, req.t_admit = slot, PREFILL, now
+                self.slots[slot] = req
             # the table row stays scratch until prefill lands: a PREFILL
             # slot rides decode steps as idle and must not touch its pages
 
@@ -740,7 +759,7 @@ class Engine:
         self._table[req.slot, keep:] = KV.SCRATCH_PAGE
         self._tables_dirty = True
 
-    def _prefill_step(self, req: Request, now: float) -> int:
+    def _prefill_step(self, req: Request, now: float, tick) -> int:
         """Run one prompt chunk; returns real tokens consumed."""
         e = self.ecfg
         c0 = req.prefill_done
@@ -748,7 +767,7 @@ class Engine:
             # first chunk of a prefix-hit request: pull the matched rows
             # out of its (shared/CoW) pages into staging, then prefill
             # only from the divergence point
-            self._load_prefix_to_staging(req)
+            self._load_prefix_to_staging(req, tick)
         n = min(e.prefill_chunk, req.n_prompt - c0)
         if c0 % e.prefill_chunk:
             # realign a warm start to the chunk grid with one short
@@ -758,7 +777,8 @@ class Engine:
             n = min(n, e.prefill_chunk - c0 % e.prefill_chunk)
         chunk = np.zeros((1, e.prefill_chunk), np.int32)
         chunk[0, :n] = req.prompt[c0:c0 + n]
-        with self._tp_scope():
+        with TraceAnnotation("engine.prefill_chunk", rid=req.rid, start=c0,
+                             tokens=n), self._tp_scope():
             logits, self._staging = self._prefill_fn(
                 self.params, {"tokens": jnp.asarray(chunk),
                               "index": jnp.int32(c0)}, self._staging)
@@ -773,9 +793,11 @@ class Engine:
                 self.prefix.insert(req.prompt, req.pages)
             # the first generated token sits at timeline index n_prompt;
             # greedy configs reduce to the original argmax bit-for-bit
-            first = int(SMP.sample_tokens(
-                logits[:, n - 1], jnp.asarray([req.rid], jnp.int32),
-                jnp.asarray([req.n_prompt], jnp.int32), self.sampler)[0])
+            with TraceAnnotation("engine.first_token", rid=req.rid):
+                first = int(SMP.sample_tokens(
+                    logits[:, n - 1], jnp.asarray([req.rid], jnp.int32),
+                    jnp.asarray([req.n_prompt], jnp.int32), self.sampler)[0])
+            tick["host_reads"] += 1
             req.out_tokens.append(first)
             req.pos = req.n_prompt
             req.state, req.t_first = DECODE, now
@@ -796,26 +818,32 @@ class Engine:
             rids[r.slot] = r.rid
         return live, tokens, positions, rids
 
-    def _decode_batch(self, now: float) -> int:
+    def _decode_batch(self, now: float, tick) -> int:
         """One batched decode step over every DECODE-state slot."""
-        live, tokens, positions, rids = self._live_batch()
-        if not live:
-            return 0
-        with self._tp_scope():
-            nxt, self.caches = self._decode_fn(
-                self.params, {"tokens": jnp.asarray(tokens),
-                              "index": jnp.asarray(positions)}, self.caches,
-                jnp.asarray(rids))
-        nxt = np.asarray(nxt)
-        for r in live:
-            tok = int(nxt[r.slot])
-            r.pos += 1
-            r.out_tokens.append(tok)
-            self._maybe_finish(r, tok, now)
-        return len(live)
+        with TraceAnnotation("engine.decode") as span:
+            live, tokens, positions, rids = self._live_batch()
+            if span.is_enabled():
+                span.set_metadata(live=len(live))
+            if not live:
+                return 0
+            with self._tp_scope():
+                nxt, self.caches = self._decode_fn(
+                    self.params, {"tokens": jnp.asarray(tokens),
+                                  "index": jnp.asarray(positions)}, self.caches,
+                    jnp.asarray(rids))
+            with TraceAnnotation("engine.readback"):
+                nxt = np.asarray(nxt)
+            tick["host_reads"] += 1
+            for r in live:
+                tok = int(nxt[r.slot])
+                r.pos += 1
+                r.out_tokens.append(tok)
+                self._maybe_finish(r, tok, now)
+            return len(live)
 
     def _spec_round(self, now: float, live: List[Request], k: int,
-                    draft_fn, accept_fn, rung_i: Optional[int] = None) -> int:
+                    draft_fn, accept_fn, tick,
+                    rung_i: Optional[int] = None) -> int:
         """One speculative round over the `live` participants: k draft
         steps under the draft policy, one k+1-token verify pass under
         the serving policy, rejection-sampled acceptance, then paged-KV
@@ -838,7 +866,7 @@ class Engine:
         # pos+k) and push the grown tables before anything reads them
         dirty = [self._commit_pages(r, r.pos + k + 1) for r in live]
         if any(dirty) or self._tables_dirty:
-            self._sync_tables()
+            self._sync_tables(tick)
             self._tables_dirty = False
         toks = jnp.asarray(tokens)
         pos = jnp.asarray(positions)
@@ -861,6 +889,7 @@ class Engine:
             drafts, None if self.sampler.greedy
             else jnp.stack(draft_probs, axis=1), logits, rid_arr, pos)
         emitted, acc = np.asarray(emitted), np.asarray(acc)
+        tick["host_reads"] += 2
         self.spec_rounds += 1
         self.spec_request_rounds += len(live)
         if rung_i is not None:
@@ -901,15 +930,17 @@ class Engine:
                         r.rung = nxt
         return len(live) * (2 * k + 1)
 
-    def _spec_decode_batch(self, now: float) -> int:
+    def _spec_decode_batch(self, now: float, tick) -> int:
         """One static-draft speculative round over every DECODE slot."""
         live = [r for r in self.slots if r is not None and r.state == DECODE]
         if not live:
             return 0
-        return self._spec_round(now, live, self.spec.k, self._draft_fn,
-                                self._accept_fn)
+        with TraceAnnotation("engine.spec_round", k=self.spec.k,
+                             live=len(live)):
+            return self._spec_round(now, live, self.spec.k, self._draft_fn,
+                                    self._accept_fn, tick)
 
-    def _spec_decode_all(self, now: float) -> int:
+    def _spec_decode_all(self, now: float, tick) -> int:
         """Adaptive tick: batch live requests by current rung, run one
         speculative round per non-empty rung group (groups snapshot up
         front — a request that switches rungs during its own round is
@@ -925,8 +956,10 @@ class Engine:
                 continue
             rg = self.rungs[i]
             t0 = time.monotonic()
-            cost += self._spec_round(now, group, rg.k, rg.draft_fn,
-                                     rg.accept_fn, rung_i=i)
+            with TraceAnnotation("engine.spec_round", k=rg.k,
+                                 live=len(group), rung=i):
+                cost += self._spec_round(now, group, rg.k, rg.draft_fn,
+                                         rg.accept_fn, tick, rung_i=i)
             self.rung_wall[i] += time.monotonic() - t0
         return cost
 
@@ -936,41 +969,59 @@ class Engine:
 
     def step(self, now: float = 0.0):
         """One scheduler tick: admit, decode the running batch, spend the
-        leftover token budget on prefill chunks."""
-        self._admit(now)
-        budget = self.ecfg.token_budget
-        if self.adaptive is not None:
-            budget -= self._spec_decode_all(now)
-        elif self.spec is not None:
-            budget -= self._spec_decode_batch(now)
-        else:
-            budget -= self._decode_batch(now)
-        while budget > 0:
-            pre = [r for r in self.slots
-                   if r is not None and r.state == PREFILL]
-            if not pre:
-                break
-            # a partially-prefilled request MUST keep the baton until its
-            # prompt is fully staged: the staging cache is shared, so
-            # switching mid-prefill would interleave two prompts' rows
-            # (there is at most one partial request by induction; a
-            # prefix-hit request starts at prefill_done == prefill_skip,
-            # so "untouched" is done == skip, not done == 0).  Ties on
-            # t_admit (same tick) then break by admission order (rid)
-            budget -= self._prefill_step(
-                min(pre, key=lambda r: (r.prefill_done == r.prefill_skip,
-                                        r.t_admit, r.rid)), now)
-        self._admit(now)        # freed slots/pages admit within the tick
-        if self._tables_dirty:
-            # one device sync per tick, after all finish/prefill events —
-            # the next tick's decode reads tables through the cache pytree.
-            # Deferring past _finish is safe: the freed slot's stale row
-            # only matters to decode, which never runs before this sync
-            self._sync_tables()
-            self._tables_dirty = False
-        self.peak_live_tokens = max(self.peak_live_tokens,
-                                    self.live_tokens())
-        self.n_steps += 1
+        leftover token budget on prefill chunks.  Under a profiler trace
+        the tick's `engine.step` span carries its counters as stats."""
+        with TraceAnnotation("engine.step") as span:
+            n_waiting, n_finished = len(self.waiting), len(self.finished)
+            # the tick's counters; helpers add their device-to-host reads
+            # and table syncs
+            tick = dict.fromkeys(("decode_live", "prefill_chunks",
+                                  "prefill_tokens", "host_reads",
+                                  "table_syncs"), 0)
+            self._admit(now)
+            tick["decode_live"] = sum(r is not None and r.state == DECODE
+                                      for r in self.slots)
+            budget = self.ecfg.token_budget
+            if self.adaptive is not None:
+                budget -= self._spec_decode_all(now, tick)
+            elif self.spec is not None:
+                budget -= self._spec_decode_batch(now, tick)
+            else:
+                budget -= self._decode_batch(now, tick)
+            while budget > 0:
+                pre = [r for r in self.slots
+                       if r is not None and r.state == PREFILL]
+                if not pre:
+                    break
+                # a partially-prefilled request MUST keep the baton until its
+                # prompt is fully staged: the staging cache is shared, so
+                # switching mid-prefill would interleave two prompts' rows
+                # (there is at most one partial request by induction; a
+                # prefix-hit request starts at prefill_done == prefill_skip,
+                # so "untouched" is done == skip, not done == 0).  Ties on
+                # t_admit (same tick) then break by admission order (rid)
+                n = self._prefill_step(
+                    min(pre, key=lambda r: (r.prefill_done == r.prefill_skip,
+                                            r.t_admit, r.rid)), now, tick)
+                tick["prefill_chunks"] += 1
+                tick["prefill_tokens"] += n
+                budget -= n
+            self._admit(now)        # freed slots/pages admit within the tick
+            if self._tables_dirty:
+                # one device sync per tick, after all finish/prefill events —
+                # the next tick's decode reads tables through the cache pytree.
+                # Deferring past _finish is safe: the freed slot's stale row
+                # only matters to decode, which never runs before this sync
+                self._sync_tables(tick)
+                self._tables_dirty = False
+            self.peak_live_tokens = max(self.peak_live_tokens,
+                                        self.live_tokens())
+            if span.is_enabled():
+                span.set_metadata(step=self.n_steps,
+                                  admitted=n_waiting - len(self.waiting),
+                                  finished=len(self.finished) - n_finished,
+                                  waiting=len(self.waiting), **tick)
+            self.n_steps += 1
 
     def live_tokens(self) -> int:
         return sum(r.pos for r in self.slots if r is not None)
