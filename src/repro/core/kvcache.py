@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .formats import get_format
 from .packing import operand_nbytes, pack_fp4, unpack_fp4
@@ -250,9 +251,43 @@ def gather_paged_kv(cache):
     return out
 
 
+def scatter_prefill_rows(pools, rows, page_ids, length, start):
+    """Traced scatter of one request's prefill rows [`start`, `length`)
+    into its pages — the relayout `write_prefill_rows` wraps, written to
+    run inside a jit with fixed shapes (one trace for every length).
+
+    pools: the QUANT_KEYS pool leaves (P, page, KV, ...); rows: leaves
+    (S, KV, ...) (one request, batch dim stripped); page_ids: (n,) i32
+    array of the request's distinct pages in timeline order, padded past
+    its own to cover all S rows; length, start: i32 scalars.  Row r
+    lands at (page_ids[r // page], r % page) when start <= r < length.
+    Every other row — before `start` (a shared or copy-on-write prefix,
+    whose pages may be read-only) or at or after `length` — is sent to a
+    page beyond the pool and dropped, so padded page ids are never
+    written.  Pure relayout: the pages receive codes/scales
+    bit-identical to the rows.  Donate the pools to the enclosing jit
+    and the write is in place.  Returns the updated QUANT_KEYS pools."""
+    n_pool, ps = pools["k_codes"].shape[:2]
+    s = rows["k_codes"].shape[0]
+    if page_ids.shape[0] * ps < s:
+        raise ValueError(f"{page_ids.shape[0]} page ids cannot hold {s} "
+                         f"rows of {ps}")
+    r = jnp.arange(s, dtype=jnp.int32)
+    page = r // ps
+    live = (r >= start) & (r < length)
+    # a dropped row's page lies past the pool, one per timeline page, so
+    # the (page, slot) pairs stay unique and the scatter need not order
+    pid = jnp.where(live, page_ids[page], n_pool + page)
+    slot = r % ps
+    return {key: pools[key].at[pid, slot].set(rows[key], mode="drop",
+                                              unique_indices=True)
+            for key in QUANT_KEYS}
+
+
 def write_prefill_rows(cache, rows, page_ids, length: int, *,
                        start: int = 0):
-    """Scatter a prefill's rows [`start`, `length`) into pages.
+    """Scatter a prefill's rows [`start`, `length`) into pages: the host
+    wrapper over `scatter_prefill_rows`.
 
     rows: contiguous-layout pytree with leaves (S, KV, ...) (one request,
     batch dim already stripped); page_ids: host list of the request's
@@ -260,10 +295,9 @@ def write_prefill_rows(cache, rows, page_ids, length: int, *,
     start: host int, first row to write (rows before it — a shared or
     copy-on-write prefix the engine matched from the prefix cache — are
     already in their pages and MUST NOT be rewritten: pages below the
-    start row may be read-only shared pages).  Copies whole pages plus
-    the partial head/tail pages — pure relayout, so the pages hold
-    codes/scales bit-identical to the staging cache's.  Returns the
-    cache with updated pools."""
+    start row may be read-only shared pages).  Pure relayout, so the
+    pages hold codes/scales bit-identical to the staging cache's.
+    Returns the cache with updated pools."""
     ps = cache["k_codes"].shape[1]
     n_need = -(-length // ps) if length else 0
     if n_need > len(page_ids):
@@ -271,17 +305,13 @@ def write_prefill_rows(cache, rows, page_ids, length: int, *,
                          f"got {len(page_ids)}")
     if not 0 <= start <= length:
         raise ValueError(f"start ({start}) outside [0, {length}]")
+    ids = np.full(-(-rows["k_codes"].shape[0] // ps), SCRATCH_PAGE, np.int32)
+    n = min(len(page_ids), ids.shape[0])
+    ids[:n] = page_ids[:n]
     out = dict(cache)
-    for key in QUANT_KEYS:
-        pool, src = out[key], rows[key]
-        for j in range(n_need):
-            if (j + 1) * ps <= start:
-                continue                    # page fully covered by prefix
-            pid = int(page_ids[j])
-            lo = max(start - j * ps, 0)
-            n = min(ps, length - j * ps)
-            pool = pool.at[pid, lo:n].set(src[j * ps + lo:j * ps + n])
-        out[key] = pool
+    out.update(scatter_prefill_rows(
+        {key: cache[key] for key in QUANT_KEYS}, rows, jnp.asarray(ids),
+        jnp.int32(length), jnp.int32(start)))
     return out
 
 
@@ -295,7 +325,6 @@ def paged_from_contiguous(ref, lengths, *, page_size: int,
     cache pytree with the block table installed.  Pure relayout — pages
     hold codes/scales bit-identical to `ref` — which makes this the
     standard paged-vs-contiguous fixture for tests and benchmarks."""
-    import numpy as np
     B = ref["k_codes"].shape[0]
     n_need = [max(1, -(-int(n) // page_size)) for n in lengths]
     if n_pages is None:

@@ -26,7 +26,8 @@ Request lifecycle — admit -> prefill -> decode -> finish/evict:
   prefill : the prompt runs in fixed-size chunks against a contiguous
             (1, S_max) *staging* cache — the PR-2 quantized-cache path,
             unchanged — then the staged rows scatter into the request's
-            pages (`write_prefill_rows`, pure relayout, bit-identical
+            pages in one jit'd program that donates the pools
+            (`scatter_prefill_rows`, pure relayout, bit-identical
             codes/scales).  The final chunk's logits yield the first
             generated token.  A prefix-hit request first materializes
             the matched rows from its (shared) pages into staging (pure
@@ -104,7 +105,7 @@ Profiler spans: while a `jax.profiler` trace runs, every tick records
 admitted, finished, waiting, host_reads, table_syncs) around its phases
 `engine.admit` (rid), `engine.decode` (live) with `engine.readback`,
 `engine.prefill_chunk` (rid, start, tokens), `engine.scatter` (rid,
-pages), `engine.first_token` (rid), `engine.table_sync`,
+pages, rows), `engine.first_token` (rid), `engine.table_sync`,
 `engine.spec_round` (k, live, rung), `engine.cow_copy` and
 `engine.prefix_load`.  They wrap host calls only; device work dispatched
 inside one runs asynchronously, so a span's length is host time.  With no
@@ -375,6 +376,9 @@ class Engine:
         self._prefill_fn = jax.jit(model.decode_step)
         self._decode_fn = jax.jit(self._make_decode_step(),
                                   donate_argnums=(2,))
+        # staging -> pages: the pools are donated (written in place);
+        # staging is not, later prompts reuse it
+        self._scatter_fn = jax.jit(self._make_scatter(), donate_argnums=(0,))
         if spec is not None:
             self.draft_pol = SPD.validate_policy_pair(spec.draft_policy,
                                                       pol)
@@ -462,6 +466,26 @@ class Engine:
 
         return step
 
+    @staticmethod
+    def _make_scatter():
+        """The jit'd staging -> pages scatter of one finished prompt over
+        every pool (the scanned groups vmapped, then each tail layer):
+        one program for every prompt length, since the page ids arrive
+        padded to the block-table width and the length and start row as
+        traced scalars (`core.kvcache.scatter_prefill_rows`)."""
+        def write(pools, staged, ids, length, start):
+            rows = {k: staged[k][0] for k in KV.QUANT_KEYS}
+            return KV.scatter_prefill_rows(pools, rows, ids, length, start)
+
+        def scatter(pools, staging, ids, length, start):
+            groups = jax.vmap(write, in_axes=(0, 0, None, None, None))(
+                pools["groups"], staging["groups"], ids, length, start)
+            tail = [write(p, s, ids, length, start)
+                    for p, s in zip(pools["tail"], staging["tail"])]
+            return {"groups": groups, "tail": tail}
+
+        return scatter
+
     @property
     def _spec_k(self) -> int:
         """Draft-window rows priced into reservations and the submit
@@ -535,31 +559,32 @@ class Engine:
 
     def _scatter_staging_to_pages(self, req: Request):
         """Copy the staged prompt rows into the request's pages (pure
-        relayout; see `core.kvcache.write_prefill_rows`).  A prefix-hit
-        request scatters only from its divergence point on — rows before
-        `prefill_skip` live in shared (or CoW-copied) pages that must
-        not be written."""
+        relayout; see `core.kvcache.scatter_prefill_rows`), one donated
+        program over every pool.  A prefix-hit request scatters only
+        from its divergence point on — rows before `prefill_skip` live
+        in shared (or CoW-copied) pages that must not be written."""
         n, start = req.n_prompt, req.prefill_skip
-        ids = req.pages
+        ids = np.full(self.ecfg.max_pages_per_req, KV.SCRATCH_PAGE, np.int32)
+        ids[:len(req.pages)] = req.pages
 
-        def copy_group(pages, staged):
-            rows = {k: staged[k][0] for k in KV.QUANT_KEYS}
-            return KV.write_prefill_rows(pages, rows, ids, n, start=start)
+        def quant(c):
+            return {k: c[k] for k in KV.QUANT_KEYS}
 
-        with TraceAnnotation("engine.scatter", rid=req.rid, pages=len(ids)):
-            g = self.caches["groups"]["p0"]
-            sg = self._staging["groups"]["p0"]
-            g = jax.vmap(copy_group)({k: g[k] for k in KV.QUANT_KEYS},
-                                     {k: sg[k] for k in KV.QUANT_KEYS})
-            self.caches["groups"]["p0"] = dict(self.caches["groups"]["p0"], **g)
-            for i, (pc, sc) in enumerate(zip(self.caches["tail"],
-                                             self._staging["tail"])):
-                rows = {k: sc[k][0] for k in KV.QUANT_KEYS}
-                self.caches["tail"][i] = KV.write_prefill_rows(pc, rows, ids, n,
-                                                               start=start)
+        with TraceAnnotation("engine.scatter", rid=req.rid,
+                             pages=len(req.pages), rows=n - start):
+            g, tail = self.caches["groups"]["p0"], self.caches["tail"]
+            st = self._staging
+            new = self._scatter_fn(
+                {"groups": quant(g), "tail": [quant(c) for c in tail]},
+                {"groups": quant(st["groups"]["p0"]),
+                 "tail": [quant(c) for c in st["tail"]]},
+                jnp.asarray(ids), jnp.int32(n), jnp.int32(start))
+            self.caches = {"groups": {"p0": dict(g, **new["groups"])},
+                           "tail": [dict(c, **t)
+                                    for c, t in zip(tail, new["tail"])]}
             if self._mesh is not None:
-                # eager scatter output sharding is compiler-chosen; pin the
-                # pool back to its canonical mesh layout (pure relayout)
+                # pin the pool to its canonical mesh layout whatever the
+                # compiler chose for the output (a no-op when it matches)
                 self.caches = self._shard_caches(self.caches)
 
     def _cow_copy(self, src: int, dst: int, n_rows: int):

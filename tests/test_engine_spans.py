@@ -100,6 +100,7 @@ def test_plain_path_spans_and_counters(model_and_params, tmp_path):
             range(0, r.n_prompt, ECFG.prefill_chunk))
         sc = [st for st in by["engine.scatter"] if st["rid"] == r.rid][0]
         assert sc["pages"] == -(-(r.n_prompt + r.max_new) // ECFG.page_size)
+        assert sc["rows"] == r.n_prompt - r.prefill_skip == r.n_prompt
     assert len([st for st in by["engine.prefill_chunk"]
                 if st["rid"] == 10]) == 3
     for i, (_, _, st) in enumerate(steps):

@@ -340,6 +340,86 @@ def test_prefill_scatter_start_skips_prefix_pages(fmt, packed):
         KV.write_prefill_rows(base, rows, pages[0], L, start=L + 1)
 
 
+def _traced_scatter_case(fmt, packed):
+    """-> (jit'd scatter, staging rows (S_max, ...), poisoned pool, the
+    request's pages) for the traced-scatter tests: S_max = 3 pages, the
+    page list one real page longer than the rows need (a decode page the
+    scatter must not touch), ids out of allocation order."""
+    n_kv, hd, s_max = 2, 16, 3 * PS
+    k, v = _raw_kv(7, 1, s_max, n_kv, hd)
+    ref = KV.update_kv_cache(
+        KV.init_kv_cache(1, s_max, n_kv, hd, fmt=fmt, packed=packed),
+        k, v, 0, fmt=fmt, packed=packed)
+    rows = {key: ref[key][0] for key in KV.QUANT_KEYS}
+    pool = KV.init_paged_kv_cache(8, PS, n_kv, hd, fmt=fmt,
+                                  packed=packed)
+    # poison the pool so "untouched" is observable
+    poison = {key: jnp.ones_like(pool[key]) for key in KV.QUANT_KEYS}
+    pages = [5, 2, 7, 3]
+
+    def scatter(*args):        # a function of its own: a jit cache of its own
+        return KV.scatter_prefill_rows(*args)
+
+    return jax.jit(scatter), rows, poison, pages
+
+
+def _expected_pool(poison, rows, pages, length, start):
+    want = {key: np.array(poison[key]) for key in KV.QUANT_KEYS}
+    for key in KV.QUANT_KEYS:
+        src = np.asarray(rows[key])
+        for r in range(start, length):
+            want[key][pages[r // PS], r % PS] = src[r]
+    return want
+
+
+def _assert_pool_bits(got, want):
+    for key in KV.QUANT_KEYS:
+        g = np.asarray(got[key])
+        assert g.dtype == want[key].dtype, key
+        assert np.array_equal(g.view(np.uint8), want[key].view(np.uint8)), key
+
+
+@pytest.mark.parametrize("fmt,packed", KV_FORMATS, ids=map(_fmt_id, KV_FORMATS))
+def test_traced_scatter_bit_identical_every_residue(fmt, packed):
+    """scatter_prefill_rows under one jit writes rows [0, length) into
+    the request's pages bit-identical to the contiguous cache, for every
+    length residue mod the page size and for length == S_max; every
+    other row of the pool — past `length`, on the unused decode page,
+    on pages the request does not own — keeps its old contents, and
+    the traced lengths never recompile."""
+    fn, rows, poison, pages = _traced_scatter_case(fmt, packed)
+    s_max = rows["k_codes"].shape[0]
+    ids = jnp.asarray(pages, jnp.int32)
+    for length in list(range(1, PS + 1)) + [2 * PS + 3, s_max]:
+        got = fn(poison, rows, ids, jnp.int32(length), jnp.int32(0))
+        _assert_pool_bits(got, _expected_pool(poison, rows, pages, length, 0))
+    assert fn._cache_size() == 1
+
+
+@pytest.mark.parametrize("fmt,packed", KV_FORMATS, ids=map(_fmt_id, KV_FORMATS))
+def test_traced_scatter_start_and_padding_untouched(fmt, packed):
+    """scatter_prefill_rows(start > 0) never writes a row before `start`
+    (full prefix pages and a CoW page's head rows keep their contents)
+    nor a row at or after `length`, and page ids padded past the
+    request's own (here a real page, so a stray write would show) are
+    never written — while rows [start, length) land bit-identical."""
+    fn, rows, poison, pages = _traced_scatter_case(fmt, packed)
+    s_max = rows["k_codes"].shape[0]
+    own = pages[:3]
+    ids = jnp.asarray(own + [6], jnp.int32)            # padded with page 6
+    cases = [(PS + 5, 2 * PS + 3),      # mid-page divergence and tail
+             (PS, 2 * PS),              # page-aligned start and end
+             (2 * PS + 1, s_max),       # last page only, to S_max
+             (PS + 2, PS + 2)]          # start == length: nothing written
+    for start, length in cases:
+        got = fn(poison, rows, ids, jnp.int32(length), jnp.int32(start))
+        _assert_pool_bits(got, _expected_pool(poison, rows, own, length,
+                                              start))
+    assert fn._cache_size() == 1
+    with pytest.raises(ValueError, match="page ids"):
+        fn(poison, rows, ids[:2], jnp.int32(PS), jnp.int32(0))
+
+
 # -----------------------------------------------------------------------------
 # byte accounting: live tokens, not B x S_max
 # -----------------------------------------------------------------------------
