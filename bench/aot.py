@@ -29,9 +29,10 @@ def compile_steps(conf: dict, name: str, device) -> dict:
     from repro.models import build_model
     from repro.serving.sampler import SamplerConfig
     from bench import model as bm
+    from bench import spec
 
     ops._interpret = lambda: False       # compile the kernels, not interpret
-    cfg = bm.model_config(conf, name)
+    cfg = spec.load_family(conf, ROOT).model_config(conf, name)
     mdl = build_model(cfg)
     g = bm.engine_geometry(conf)
     one = SingleDeviceSharding(device)

@@ -2,8 +2,9 @@
 
 After the window closes, a sample of the requests the server finished
 -- the longest of them and others drawn from the seed, until at least
-`sample_tokens` served tokens are in it, or all of them -- runs through the float32
-reference (`bench/reference`) over its prompt and served tokens.  Each
+`sample_tokens` served tokens are in it, or all of them -- runs through
+the float32 reference of the configuration's family
+(`bench/reference/<family>.py`) over its prompt and served tokens.  Each
 served token is read for its gap: how far its reference logit lies
 below the reference's best at that position.  Greedy decoding in a
 sound server puts that gap near zero; the configuration file holds the
@@ -14,6 +15,8 @@ for an fp8 policy).
 from __future__ import annotations
 
 import numpy as np
+
+from bench import spec
 
 
 def sample(tracks, seed: int, min_tokens: int) -> list:
@@ -44,10 +47,11 @@ def bucket(n: int, conf: dict) -> int:
     return -(-n // step) * step
 
 
-def compare(weights, conf: dict, picked, *, quant=None) -> dict:
+def compare(weights, conf: dict, picked, *, quant=None,
+            root: str = spec.ROOT) -> dict:
     """Gaps of every served token of the picked requests; with `quant`,
     also the control's gaps on the same positions."""
-    from bench.reference import dense_decoder as ref
+    ref = spec.load_reference(conf, root)
     gaps, cgaps = [], []
     for t in picked:
         out = np.asarray(t.req.out_tokens, np.int32)

@@ -1,10 +1,12 @@
 """The system under test, built from a configuration file and a seed.
 
-The benchmark makes the weights itself, on the device, in one jitted
-call from the seed and in the dtype they are served in; the program is
-asked only for the layout of its parameter tree.  The server is then
-built through the program's own entry point (`serve.make_engine` ->
-`Engine`) with the configuration's engine geometry.
+The program's `ModelConfig` comes from the configuration's family
+(`spec.load_family(conf).model_config`).  The benchmark makes the
+weights itself, on the device, in one jitted call from the seed and in
+the dtype they are served in; the program is asked only for the layout
+of its parameter tree.  The server is then built through the program's
+own entry point (`serve.make_engine` -> `Engine`) with the
+configuration's engine geometry.
 """
 from __future__ import annotations
 
@@ -14,25 +16,6 @@ import jax.numpy as jnp
 
 def engine_geometry(conf: dict) -> dict:
     return {k: v["value"] for k, v in conf["engine"].items()}
-
-
-def model_config(conf: dict, name: str):
-    """The program's `ModelConfig` for a configuration file: published
-    sizes (as run) and the execution settings the repository serves
-    dense decoders with."""
-    from repro.models.config import ModelConfig
-    return ModelConfig(
-        name=name, family="decoder",
-        n_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"], head_dim=conf["head_dim"],
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        qk_norm=bool(conf["qk_norm"]), rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["rms_norm_eps"]),
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        max_seq=conf["max_position_embeddings"],
-        dtype="bf16", policy=conf["policy"], remat="full", attn_chunk=512,
-        logits_chunk=512)
 
 
 def seed_key(seed: int):
@@ -79,15 +62,14 @@ def make_weights(model, seed: int):
     return jax.jit(build)(seed_key(seed))
 
 
-def make_engine(conf: dict, name: str, params, mix: dict, seed: int):
-    """The program's server for this configuration, through the normal
-    entry point, with the given weights."""
+def make_engine(conf: dict, cfg, params, mix: dict, seed: int):
+    """The program's server for this configuration and its `ModelConfig`
+    `cfg`, through the normal entry point, with the given weights."""
     from repro.launch import serve
     from repro.models import build_model
-    cfg = model_config(conf, name)
     g = engine_geometry(conf)
     args = serve.parser().parse_args([
-        "--arch", name, "--engine", "--policy", conf["policy"],
+        "--arch", cfg.name, "--engine", "--policy", conf["policy"],
         "--page-size", str(g["page_size"]), "--pages", str(g["n_pages"]),
         "--max-batch", str(g["max_batch"]),
         "--max-pages-per-req", str(g["max_pages_per_req"]),

@@ -4,16 +4,17 @@
 
 Phases, in one process: check that JAX sees a TPU with the chips the
 cell asks for; turn on the persistent compilation cache inside the
-checkout; build the weights on the device from the seed and the server
-through the program's own entry point; warm up the cell's shapes and
-load the server with the requests it would hold under this traffic (the
-prefill chunk, the decode step, and a prompt length of every residue
-modulo the page size, so that every eager page-scatter shape compiles);
-serve the cell's open-loop traffic for `--seconds`; read the peak device
-memory; free the server; and check the served tokens against the float32
-reference.  With `--trace 1` the window runs under the profiler and the
-line carries the per-layer metrics; with `--trace 0`, the end-to-end
-ones.  The last lines on stderr, and the `checks` key that closes the
+checkout; take the program's model config from the configuration's
+family (`bench/families/<family>.py`); build the weights on the device
+from the seed and the server through the program's own entry point;
+warm up the cell's shapes and load the server with the requests it
+would hold under this traffic (the prefill chunk, the decode step, and
+a prompt length of every residue modulo the page size, so that every
+eager page-scatter shape compiles); serve the cell's open-loop traffic
+for `--seconds`; read the peak device memory; free the server; and
+check the served tokens against the family's float32 reference.  With
+`--trace 1` the window runs under the profiler and the line carries the
+per-layer metrics; with `--trace 0`, the end-to-end ones.  The last lines on stderr, and the `checks` key that closes the
 result line, give each number compared with its limit.
 
 Calibration options, never used by the benchmark's own runs: `--rate`
@@ -193,6 +194,7 @@ def run_cell(cell, args, devices, root: str = ROOT, fault=None):
     from bench.traffic import gen
     counter = CompileCounter()
     conf = cell.config
+    cfg = spec.load_family(conf, root).model_config(conf, cell.config_name)
     rate = args.rate or cell.rate
     geometry = model.engine_geometry(conf)
     with open(os.path.join(root, "bench", "peaks.json")) as f:
@@ -203,12 +205,10 @@ def run_cell(cell, args, devices, root: str = ROOT, fault=None):
         return None
     from repro.models import build_model
     t0 = time.monotonic()
-    weights = model.make_weights(build_model(model.model_config(
-        conf, cell.config_name)), args.seed)
+    weights = model.make_weights(build_model(cfg), args.seed)
     jax.block_until_ready(weights)
     t1 = time.monotonic()
-    engine = model.make_engine(conf, cell.config_name, weights, cell.mix,
-                               args.seed)
+    engine = model.make_engine(conf, cfg, weights, cell.mix, args.seed)
     t2 = time.monotonic()
     ps = geometry["page_size"]
     held_reqs = gen.preload(cell.mix, cell.preload, args.seed,
@@ -266,7 +266,7 @@ def run_cell(cell, args, devices, root: str = ROOT, fault=None):
     t_c = time.monotonic()
     picked = check.sample(rec["tracks"], args.seed,
                           conf["correct"].get("sample_tokens", 512))
-    res = check.compare(weights, conf, picked, quant=args.control)
+    res = check.compare(weights, conf, picked, quant=args.control, root=root)
     log(f"reference: {res} in {time.monotonic() - t_c!r} s")
     unserved = sum(1 for t in rec["tracks"] if t.in_window and t.first is None)
     checks, correct = judge(res, unserved, conf)
@@ -284,7 +284,7 @@ def run_cell(cell, args, devices, root: str = ROOT, fault=None):
         device.update(busy_s=red["busy_s"], window_s=red["window_s"])
         rd = {"seconds": rec["seconds"], "steps": rec["steps"],
               "tracks": rec["tracks"], "trace": red, "conf": conf,
-              "geometry": geometry, "peak": peaks[kind]}
+              "geometry": geometry, "peak": peaks[kind], "root": root}
         for m in cell.per_layer:
             v = spec.load_reader(m["name"], root)(rd)
             if v is not None and math.isfinite(v):

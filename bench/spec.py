@@ -8,6 +8,15 @@ entries; nothing here names a cell, configuration, mix or metric.
   bench/cells/<cell>.json       the rate a cell offers, the requests in
                                 flight when its window opens, and why
   bench/metrics/<metric>.py     a reader: `read(record) -> float | None`
+  bench/families/<family>.py    what the harness needs of a model family:
+                                the program's model config and the census
+                                of its work
+  bench/reference/<family>.py   the family's plain float32 reference:
+                                `served_gaps(weights, conf, tokens,
+                                targets, length=, quant=)`
+
+A configuration file names its family under the key `family`; there is
+no default, so a new architecture enters as files too.
 """
 from __future__ import annotations
 
@@ -16,6 +25,7 @@ import importlib.util
 import json
 import os
 import re
+import sys
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
@@ -54,8 +64,14 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
                        f"known: {sorted(cells)}")
     w = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
-    with open(os.path.join(root, configs[w["config"]]["file"])) as f:
+    conf_file = os.path.join(root, configs[w["config"]]["file"])
+    with open(conf_file) as f:
         config = json.load(f)
+    for kind in ("families", "reference"):
+        path = _family_file(config, kind, root, conf_file)
+        if not os.path.exists(path):
+            raise FileNotFoundError(f"{conf_file} names family "
+                                    f"{config['family']!r}; no file {path}")
     bdir = os.path.join(root, "bench")
     with open(os.path.join(bdir, "traffic", f"{w['traffic']}.json")) as f:
         mix = json.load(f)
@@ -70,11 +86,49 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
                            if _reports(m, name)])
 
 
-def load_reader(metric: str, root: str = ROOT):
-    """The `read` function of bench/metrics/<metric>.py."""
-    path = os.path.join(root, "bench", "metrics", f"{metric}.py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
+def _module(path: str, name: str):
+    """The module of a file under bench/, loaded once per process and
+    path (a reference's compiled programs then serve every run of a
+    sweep)."""
+    name = name.replace(".", "_").replace("-", "_")
+    mod = sys.modules.get(name)
+    if mod is not None and mod.__file__ == path:
+        return mod
+    if not os.path.exists(path):
+        raise FileNotFoundError(f"no file {path}")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    sys.modules[name] = mod
+    return mod
+
+
+def load_reader(metric: str, root: str = ROOT):
+    """The `read` function of bench/metrics/<metric>.py."""
+    return _module(os.path.join(root, "bench", "metrics", f"{metric}.py"),
+                   "bench_metric_" + metric).read
+
+
+def _family_file(conf: dict, kind: str, root: str,
+                 conf_file: str = "the configuration") -> str:
+    family = conf.get("family")
+    if not isinstance(family, str) or not NAME.match(family):
+        raise ValueError(f"{conf_file} names no family: give it a key "
+                         f"\"family\" that names bench/families/<family>.py "
+                         f"and bench/reference/<family>.py")
+    return os.path.join(root, "bench", kind, f"{family}.py")
+
+
+def load_family(conf: dict, root: str = ROOT):
+    """The module bench/families/<family>.py of the configuration's
+    family: `model_config`, `step_flops`, `fused_linears`,
+    `paged_layers`."""
+    path = _family_file(conf, "families", root)
+    return _module(path, "bench_family_" + conf["family"])
+
+
+def load_reference(conf: dict, root: str = ROOT):
+    """The module bench/reference/<family>.py of the configuration's
+    family: `served_gaps`."""
+    path = _family_file(conf, "reference", root)
+    return _module(path, "bench_reference_" + conf["family"])

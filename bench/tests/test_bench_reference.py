@@ -9,10 +9,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from bench import check, model
-from bench.reference import dense_decoder as ref
+from bench import check, model, spec
 
-BASE = {"intermediate_size": 128, "num_hidden_layers": 2,
+BASE = {"family": "dense_decoder",
+        "intermediate_size": 128, "num_hidden_layers": 2,
         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
         "vocab_size": 256, "rms_norm_eps": 1e-6, "rope_theta": 1e6,
         "max_position_embeddings": 64, "policy": "kv16_attn_f32",
@@ -21,12 +21,13 @@ QWEN = dict(BASE, hidden_size=64, qk_norm=True, tie_word_embeddings=True)
 NEMO = dict(BASE, hidden_size=80, qk_norm=False, tie_word_embeddings=False,
             rms_norm_eps=1e-5)
 CONFS = pytest.mark.parametrize("conf", [QWEN, NEMO], ids=["qwen", "nemo"])
+ref = spec.load_reference(BASE)
 
 
 def _program(conf, policy):
     from repro.models import build_model
-    cfg = model.model_config(conf, "t").replace(dtype="float32",
-                                                policy=policy, remat="none")
+    cfg = spec.load_family(conf).model_config(conf, "t").replace(
+        dtype="float32", policy=policy, remat="none")
     m = build_model(cfg)
     return cfg, m, model.make_weights(m, 2**32 + 3)
 

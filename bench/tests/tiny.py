@@ -29,8 +29,9 @@ def cell():
 def root(tmp_path) -> str:
     """A checkout-like root whose peak table knows the CPU."""
     r = tmp_path / "root"
-    shutil.copytree(os.path.join(spec.BENCH, "metrics"),
-                    r / "bench" / "metrics")
+    for part in ("metrics", "families", "reference"):
+        shutil.copytree(os.path.join(spec.BENCH, part), r / "bench" / part,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     (r / "bench" / "peaks.json").write_text(json.dumps(
         {"cpu": {"bf16_flops": 1e12, "hbm_bytes_per_s": 1e11}}))
     return str(r)
